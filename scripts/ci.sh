@@ -45,7 +45,8 @@ python scripts/check_test_budget.py "$report" --budget 60
 
 echo "== kernel launch-policy autotune smoke =="
 # measured autotune round-trip on a tiny shape, against a throwaway
-# cache dir so CI never touches (or depends on) ~/.cache/repro_tune;
+# cache dir so CI never touches (or depends on) the checkout's
+# .repro_tune tables;
 # the second invocation proves the table survives a process boundary
 # and is applied without re-measurement
 tune_cache="$(mktemp -d)"

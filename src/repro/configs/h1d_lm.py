@@ -22,7 +22,7 @@ CONFIGS = {
 }
 
 SMOKES = {
-    k: (lambda: ModelConfig(
+    k: (lambda k=k: ModelConfig(
         name=f"{k}-smoke", family="dense", num_layers=2, d_model=64,
         num_heads=4, num_kv_heads=4, head_dim=16, d_ff=128, vocab_size=512,
         attention="h1d", nr=8, tie_embeddings=True))

@@ -102,14 +102,15 @@ def _fwd_kernel(*refs, nr: int, mode: str, tq: int, lk: int):
     qi = it * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
 
     def term(k, v, w, k0):
-        """k: (TK, d), v: (TK, dv), w: (TK,), k0: global col offset."""
+        """k: (TK, d), v: (TK, dv), w: (TK, 1), k0: global col offset."""
         tk = k.shape[0]
         ki = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, tk), 1)
         s = jax.lax.dot_general(
             q, k.astype(f32), (((1,), (1,)), ((), ())),
             preferred_element_type=f32)                # (TQ, TK)
-        allow = band_mask(qi, ki, nr, mode, lk) & (w[None, :] > 0)
-        return jnp.where(allow, s, NEG_INF), v.astype(f32), w.astype(f32)
+        w = key_row(w)
+        allow = band_mask(qi, ki, nr, mode, lk) & (w > 0)
+        return jnp.where(allow, s, NEG_INF), v.astype(f32), w
 
     # halo refs are exact nr-row blocks (the BlockSpecs fetch only the
     # needed edge of the neighbouring tile, not the whole tile)
@@ -121,22 +122,37 @@ def _fwd_kernel(*refs, nr: int, mode: str, tq: int, lk: int):
         terms.append(
             term(kn_ref[0], vn_ref[0], wn_ref[0], (it + 1) * tq))
 
+    _combine(terms, y_ref, dn_ref, m_ref)
+
+
+def _combine(terms, y_ref, dn_ref, m_ref):
+    """Single-max softmax over a tile's bands -> (y, dn, m) blocks.
+    Row statistics are (TQ, 1) columns, the layout of their HBM blocks."""
+    f32 = jnp.float32
     m = jnp.maximum(
-        functools.reduce(jnp.maximum, [s.max(axis=1) for s, _, _ in terms]),
-        _MIN_M)                                        # (TQ,)
+        functools.reduce(jnp.maximum,
+                         [s.max(axis=1, keepdims=True) for s, _, _ in terms]),
+        _MIN_M)                                        # (TQ, 1)
     y = None
     dn = None
     for s, v, w in terms:
-        a = jnp.exp(s - m[:, None])
+        a = jnp.exp(s - m)
         yt = jax.lax.dot_general(a, v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=f32)
-        dt = jnp.sum(a * w[None, :], axis=1)
+        dt = jnp.sum(a * w, axis=1, keepdims=True)
         y = yt if y is None else y + yt
         dn = dt if dn is None else dn + dt
 
     y_ref[0, 0] = y.astype(y_ref.dtype)
     dn_ref[0, 0] = dn.astype(dn_ref.dtype)
     m_ref[0, 0] = m.astype(m_ref.dtype)
+
+
+def key_row(w):
+    """(TK, 1) key-weight column block -> (1, TK) f32 row.  Key weights
+    travel as (B, L, 1) columns so that nr-row halo blocks satisfy
+    Mosaic's tiling rule; the masks need them along the lane axis."""
+    return w.astype(jnp.float32).reshape(1, w.shape[0])
 
 
 def _fwd_sub_kernel(*refs, nr: int, ratio: int, tq: int, lk: int):
@@ -169,8 +185,9 @@ def _fwd_sub_kernel(*refs, nr: int, ratio: int, tq: int, lk: int):
         s = jax.lax.dot_general(
             q, k.astype(f32), (((1,), (1,)), ((), ())),
             preferred_element_type=f32)               # (TQ, TK)
-        allow = band_mask(qi, ki, nr, SUB_MODE, lk, ratio) & (w[None, :] > 0)
-        return jnp.where(allow, s, NEG_INF), v.astype(f32), w.astype(f32)
+        w = key_row(w)
+        allow = band_mask(qi, ki, nr, SUB_MODE, lk, ratio) & (w > 0)
+        return jnp.where(allow, s, NEG_INF), v.astype(f32), w
 
     if nq <= tq:
         tqc = tq // ratio                             # coarse rows per tile
@@ -178,28 +195,13 @@ def _fwd_sub_kernel(*refs, nr: int, ratio: int, tq: int, lk: int):
         terms = [term(kp_ref[0], vp_ref[0], wp_ref[0], it * tqc - nr)]
         if tqc > nr:
             terms.append(term(ks_ref[0, :tqc - nr, :], vs_ref[0, :tqc - nr, :],
-                              ws_ref[0, :tqc - nr], it * tqc))
+                              ws_ref[0, :tqc - nr, :], it * tqc))
     else:
         s_blk = nq // tq                              # query tiles per block
         k0 = (it // s_blk - 1) * nr                   # coarse block I-1
         terms = [term(kb_ref[0], vb_ref[0], wb_ref[0], k0)]
 
-    m = jnp.maximum(
-        functools.reduce(jnp.maximum, [s.max(axis=1) for s, _, _ in terms]),
-        _MIN_M)
-    y = None
-    dn = None
-    for s, v, w in terms:
-        a = jnp.exp(s - m[:, None])
-        yt = jax.lax.dot_general(a, v, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=f32)
-        dt = jnp.sum(a * w[None, :], axis=1)
-        y = yt if y is None else y + yt
-        dn = dt if dn is None else dn + dt
-
-    y_ref[0, 0] = y.astype(y_ref.dtype)
-    dn_ref[0, 0] = dn.astype(dn_ref.dtype)
-    m_ref[0, 0] = m.astype(m_ref.dtype)
+    _combine(terms, y_ref, dn_ref, m_ref)
 
 
 def sub_kv_specs(nr: int, ratio: int, tq: int):
@@ -218,26 +220,23 @@ def sub_kv_specs(nr: int, ratio: int, tq: int):
         # prev-halo: the single nr-row coarse block just before this
         # tile's coarse window (exact fetch, index map in nr units)
         prev_map = lambda b, g, i: (b, jnp.maximum(i * tbc - 1, 0), 0)
-        wself_map = lambda b, g, i: (b, i)
-        wprev_map = lambda b, g, i: (b, jnp.maximum(i * tbc - 1, 0))
 
         def build(k, v, w, d_, dv_):
             specs = [pl.BlockSpec((1, tqc, d_), self_map),
                      pl.BlockSpec((1, nr, d_), prev_map),
                      pl.BlockSpec((1, tqc, dv_), self_map),
                      pl.BlockSpec((1, nr, dv_), prev_map),
-                     pl.BlockSpec((1, tqc), wself_map),
-                     pl.BlockSpec((1, nr), wprev_map)]
+                     pl.BlockSpec((1, tqc, 1), self_map),
+                     pl.BlockSpec((1, nr, 1), prev_map)]
             return specs, [k, k, v, v, w, w]
         return build, "wide"
     s_blk = nq // tq
     blk_map = lambda b, g, i: (b, jnp.maximum(i // s_blk - 1, 0), 0)
-    wblk_map = lambda b, g, i: (b, jnp.maximum(i // s_blk - 1, 0))
 
     def build(k, v, w, d_, dv_):
         specs = [pl.BlockSpec((1, nr, d_), blk_map),
                  pl.BlockSpec((1, nr, dv_), blk_map),
-                 pl.BlockSpec((1, nr), wblk_map)]
+                 pl.BlockSpec((1, nr, 1), blk_map)]
         return specs, [k, v, w]
     return build, "deep"
 
@@ -265,35 +264,41 @@ def band_attention_sub_fwd(
     if nq <= tq:
         assert (tq // ratio) % nr == 0, (tq, ratio, nr)
     nt = Lq // tq
-    f32 = jnp.float32
 
     in_specs = [pl.BlockSpec((1, 1, tq, d), lambda b, g, i: (b, g, i, 0))]
     build, layout = sub_kv_specs(nr, ratio, tq)
-    kv_specs, kv_inputs = build(k, v, w, d, dv)
+    kv_specs, kv_inputs = build(k, v, w[..., None], d, dv)
     in_specs += kv_specs
     inputs = [q] + kv_inputs
 
-    out_shape = (
-        jax.ShapeDtypeStruct((B, G, Lq, dv), f32),
-        jax.ShapeDtypeStruct((B, G, Lq), f32),
-        jax.ShapeDtypeStruct((B, G, Lq), f32),
-    )
-    out_specs = (
-        pl.BlockSpec((1, 1, tq, dv), lambda b, g, i: (b, g, i, 0)),
-        pl.BlockSpec((1, 1, tq), lambda b, g, i: (b, g, i)),
-        pl.BlockSpec((1, 1, tq), lambda b, g, i: (b, g, i)),
-    )
-
     kernel = functools.partial(_fwd_sub_kernel, nr=nr, ratio=ratio, tq=tq,
                                lk=Lk)
-    return launch(
+    y, dn, m = launch(
         kernel, family="sub_fwd", grid=(B, G, nt),
-        in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        in_specs=in_specs, out_specs=_out_specs(tq, dv),
+        out_shape=_out_shape(B, G, Lq, dv),
         operands=inputs, interpret=interpret,
         in_names=("q",) + SUB_KV_NAMES[layout],
         out_names=("y", "dn", "m"),
         meta=dict(mode=SUB_MODE, nr=nr, ratio=ratio, tq=tq, lk=Lk,
                   layout=layout))
+    return y, dn[..., 0], m[..., 0]
+
+
+def _out_shape(B, G, L, dv):
+    """f32 (y, dn, m); the row statistics are (B, G, L, 1) columns so
+    their (tq, 1) blocks meet Mosaic's tiling rule for any G."""
+    f32 = jnp.float32
+    return (jax.ShapeDtypeStruct((B, G, L, dv), f32),
+            jax.ShapeDtypeStruct((B, G, L, 1), f32),
+            jax.ShapeDtypeStruct((B, G, L, 1), f32))
+
+
+def _out_specs(tq, dv):
+    tile = lambda b, g, i: (b, g, i, 0)
+    return (pl.BlockSpec((1, 1, tq, dv), tile),
+            pl.BlockSpec((1, 1, tq, 1), tile),
+            pl.BlockSpec((1, 1, tq, 1), tile))
 
 
 def band_attention_fwd(
@@ -322,7 +327,6 @@ def band_attention_fwd(
     assert L % tq == 0 and tq % nr == 0 and tq >= nr, (L, tq, nr)
     nt = L // tq
     causal = mode.endswith("causal")
-    f32 = jnp.float32
 
     # self operand: the full tile; halo operands: exact nr-row blocks
     # at the neighbouring tile's edge (index maps count nr-row blocks),
@@ -332,43 +336,24 @@ def band_attention_fwd(
     self_map = lambda b, g, i: (b, i, 0)
     prev_map = lambda b, g, i: (b, jnp.maximum(i * tb - 1, 0), 0)
     next_map = lambda b, g, i: (b, jnp.minimum((i + 1) * tb, nb - 1), 0)
-    wself_map = lambda b, g, i: (b, i)
-    wprev_map = lambda b, g, i: (b, jnp.maximum(i * tb - 1, 0))
-    wnext_map = lambda b, g, i: (b, jnp.minimum((i + 1) * tb, nb - 1))
 
     in_specs = [pl.BlockSpec((1, 1, tq, d), lambda b, g, i: (b, g, i, 0))]
     inputs = [q]
     kmaps = [(tq, self_map), (nr, prev_map)] + (
         [] if causal else [(nr, next_map)])
-    wmaps = [(tq, wself_map), (nr, wprev_map)] + (
-        [] if causal else [(nr, wnext_map)])
-    for rows, mp in kmaps:
-        in_specs.append(pl.BlockSpec((1, rows, d), mp))
-        inputs.append(k)
-    for rows, mp in kmaps:
-        in_specs.append(pl.BlockSpec((1, rows, dv), mp))
-        inputs.append(v)
-    for rows, mp in wmaps:
-        in_specs.append(pl.BlockSpec((1, rows), mp))
-        inputs.append(w)
-
-    out_shape = (
-        jax.ShapeDtypeStruct((B, G, L, dv), f32),
-        jax.ShapeDtypeStruct((B, G, L), f32),
-        jax.ShapeDtypeStruct((B, G, L), f32),
-    )
-    out_specs = (
-        pl.BlockSpec((1, 1, tq, dv), lambda b, g, i: (b, g, i, 0)),
-        pl.BlockSpec((1, 1, tq), lambda b, g, i: (b, g, i)),
-        pl.BlockSpec((1, 1, tq), lambda b, g, i: (b, g, i)),
-    )
+    for arr, width in ((k, d), (v, dv), (w[..., None], 1)):
+        for rows, mp in kmaps:
+            in_specs.append(pl.BlockSpec((1, rows, width), mp))
+            inputs.append(arr)
 
     kernel = functools.partial(_fwd_kernel, nr=nr, mode=mode, tq=tq, lk=L)
     halo = ("self", "prev") if causal else ("self", "prev", "next")
-    return launch(
+    y, dn, m = launch(
         kernel, family="band_fwd", grid=(B, G, nt),
-        in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        in_specs=in_specs, out_specs=_out_specs(tq, dv),
+        out_shape=_out_shape(B, G, L, dv),
         operands=inputs, interpret=interpret,
         in_names=("q",) + tuple(f"{a}_{h}" for a in "kvw" for h in halo),
         out_names=("y", "dn", "m"),
         meta=dict(mode=mode, nr=nr, tq=tq, lk=L))
+    return y, dn[..., 0], m[..., 0]

@@ -54,33 +54,44 @@ from jax.experimental import pallas as pl
 
 from repro.analysis.contracts import launch
 
-from .h1d_block import (band_mask, sub_kv_specs, NEG_INF, MODES, SUB_MODE,
-                        SUB_KV_NAMES)
+from .h1d_block import (band_mask, key_row, sub_kv_specs, NEG_INF, MODES,
+                        SUB_MODE, SUB_KV_NAMES)
 
 
 def _recompute(q, k, w, m, qi, ki, *, nr: int, mode: str, lk: int,
                ratio: int = 1, lq: int = None):
     """Re-materialize one band: masked scores -> (a, ind).
 
-    q: (nq, d) f32; k: (nk, d) f32; w: (nk,) f32; m: (nq,) f32 saved
-    row-max; qi: (nq, 1) / ki: (1, nk) global indices.  Returns
-    ``a = exp(s - m)`` (exactly 0 off-band via the NEG_INF mask) and the
-    argmax indicator ``ind = (s == m)`` as f32.  Query rows outside
-    [0, lq) (clamped neighbour tiles at the sequence edges) are masked
-    here -- ``band_mask`` itself only bounds-checks keys.  ``lq``
-    defaults to ``lk``; the ``sub`` mode passes the fine query length
-    (= lk * ratio) since its key axis is coarse.
+    q: (nq, d) f32; k: (nk, d) f32; w: (1, nk) f32 key-weight row; m:
+    (nq, 1) f32 saved row-max column; qi: (nq, 1) / ki: (1, nk) global
+    indices.  Returns ``a = exp(s - m)`` (exactly 0 off-band via the
+    NEG_INF mask) and the argmax indicator ``ind = (s == m)`` as f32.
+    Query rows outside [0, lq) (clamped neighbour tiles at the sequence
+    edges) are masked here -- ``band_mask`` itself only bounds-checks
+    keys.  ``lq`` defaults to ``lk``; the ``sub`` mode passes the fine
+    query length (= lk * ratio) since its key axis is coarse.
     """
     f32 = jnp.float32
     lq = lk if lq is None else lq
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=f32)
-    allow = band_mask(qi, ki, nr, mode, lk, ratio) & (w[None, :] > 0)
+    allow = band_mask(qi, ki, nr, mode, lk, ratio) & (w > 0)
     allow = allow & (qi >= 0) & (qi < lq)
     s = jnp.where(allow, s, NEG_INF)
-    a = jnp.exp(s - m[:, None])
-    ind = (s == m[:, None]).astype(f32)
+    a = jnp.exp(s - m)
+    ind = (s == m).astype(f32)
     return a, ind
+
+
+def _add_rows(x, h, start: int):
+    """``x`` with rows ``[start, start + len(h))`` incremented by ``h``
+    (static offsets, built as a sublane concatenate)."""
+    n = h.shape[0]
+    parts = [x[:start]] if start else []
+    parts.append(x[start:start + n] + h)
+    if start + n < x.shape[0]:
+        parts.append(x[start + n:])
+    return jnp.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
 
 
 def _dq_kernel(*refs, nr: int, mode: str, tq: int, lk: int):
@@ -96,20 +107,20 @@ def _dq_kernel(*refs, nr: int, mode: str, tq: int, lk: int):
     it = pl.program_id(2)
     f32 = jnp.float32
     q = q_ref[0, 0].astype(f32)                        # (TQ, d)
-    m = m_ref[0, 0].astype(f32)                        # (TQ,)
+    m = m_ref[0, 0].astype(f32)                        # (TQ, 1)
     gy = gy_ref[0, 0].astype(f32)                      # (TQ, dv)
-    gdn = gdn_ref[0, 0].astype(f32)                    # (TQ,)
-    gmh = gmh_ref[0, 0].astype(f32)                    # (TQ,)
+    gdn = gdn_ref[0, 0].astype(f32)                    # (TQ, 1)
+    gmh = gmh_ref[0, 0].astype(f32)                    # (TQ, 1)
     qi = it * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
 
     def band(k, v, w, k0):
-        k, v, w = k.astype(f32), v.astype(f32), w.astype(f32)
+        k, v, w = k.astype(f32), v.astype(f32), key_row(w)
         tk = k.shape[0]
         ki = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, tk), 1)
         a, ind = _recompute(q, k, w, m, qi, ki, nr=nr, mode=mode, lk=lk)
         da = jax.lax.dot_general(gy, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=f32)
-        da = da + gdn[:, None] * w[None, :]
+        da = da + gdn * w
         return a * da, ind, k
 
     # halo refs are exact nr-row blocks (see band_attention_fwd's specs)
@@ -119,20 +130,73 @@ def _dq_kernel(*refs, nr: int, mode: str, tq: int, lk: int):
     ]
     if not causal:
         bands.append(band(kn_ref[0], vn_ref[0], wn_ref[0], (it + 1) * tq))
+    _store_dq(bands, gmh, dq_ref, gmn_ref)
 
+
+def _store_dq(bands, gmh, dq_ref, gmn_ref):
+    """Tie-split max gradient ``gmn = gmh / count`` and ``dq`` from a
+    query tile's bands of ``(a * da, ind, k)``."""
+    f32 = jnp.float32
     count = functools.reduce(
-        jnp.add, [ind.sum(axis=1) for _, ind, _ in bands])   # (TQ,)
+        jnp.add, [ind.sum(axis=1, keepdims=True) for _, ind, _ in bands])
     gmn = jnp.where(count > 0, gmh / jnp.maximum(count, 1.0), 0.0)
 
     dq = None
     for ds0, ind, k in bands:
-        ds = ds0 + gmn[:, None] * ind
+        ds = ds0 + gmn * ind
         dqt = jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
                                   preferred_element_type=f32)
         dq = dqt if dq is None else dq + dqt
 
     dq_ref[0, 0] = dq.astype(dq_ref.dtype)
     gmn_ref[0, 0] = gmn.astype(gmn_ref.dtype)
+
+
+def _band_dkvw(qrows, gyrows, gdnrows, mrows, gmnrows, q0,
+               krows, vrows, wrows, k0, *, nr, mode, lk, ratio=1, lq=None):
+    """One (query-rows x key-rows) band of the dK/dV/dW pass.  Query
+    statistics are (nq, 1) columns; ``wrows`` is the (nk, 1) key-weight
+    column.  Returns dK (nk, d), dV (nk, dv), dW (nk, 1)."""
+    f32 = jnp.float32
+    nq = qrows.shape[0]
+    nk = krows.shape[0]
+    qi = q0 + jax.lax.broadcasted_iota(jnp.int32, (nq, 1), 0)
+    ki = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, nk), 1)
+    w = key_row(wrows)
+    a, ind = _recompute(qrows, krows, w, mrows, qi, ki, nr=nr, mode=mode,
+                        lk=lk, ratio=ratio, lq=lq)
+    da = jax.lax.dot_general(gyrows, vrows, (((1,), (1,)), ((), ())),
+                             preferred_element_type=f32)
+    da = da + gdnrows * w
+    ds = a * da + gmnrows * ind
+    dk_b = jax.lax.dot_general(ds, qrows, (((0,), (0,)), ((), ())),
+                               preferred_element_type=f32)   # (nk, d)
+    dv_b = jax.lax.dot_general(a, gyrows, (((0,), (0,)), ((), ())),
+                               preferred_element_type=f32)   # (nk, dv)
+    dw_b = jnp.sum(a * gdnrows, axis=0, keepdims=True)       # (1, nk)
+    return dk_b, dv_b, dw_b.reshape(nk, 1)
+
+
+def _tile_rows(*refs, rows=slice(None)):
+    """Read the f32 (rows, ...) slab of each (1, 1, R, ...) query ref."""
+    return [r[0, 0, rows].astype(jnp.float32) for r in refs]
+
+
+def _accumulate(first, dk, dvv, dw, dk_ref, dv_ref, dw_ref):
+    """Write the key tile's gradients on the ``first`` visit of its
+    output block and accumulate on later ones: the output index maps
+    ignore the inner grid axes, so the block stays resident in VMEM."""
+    @pl.when(first)
+    def _init():
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dvv.astype(dv_ref.dtype)
+        dw_ref[0] = dw.astype(dw_ref.dtype)
+
+    @pl.when(jnp.logical_not(first))
+    def _acc():
+        dk_ref[0] += dk.astype(dk_ref.dtype)
+        dv_ref[0] += dvv.astype(dv_ref.dtype)
+        dw_ref[0] += dw.astype(dw_ref.dtype)
 
 
 def _dkvw_kernel(*refs, nr: int, mode: str, tq: int, lk: int):
@@ -154,72 +218,36 @@ def _dkvw_kernel(*refs, nr: int, mode: str, tq: int, lk: int):
     f32 = jnp.float32
     k = k_ref[0].astype(f32)                           # (TK, d)
     v = v_ref[0].astype(f32)                           # (TK, dv)
-    w = w_ref[0].astype(f32)                           # (TK,)
-
-    def band(qrows, gyrows, gdnrows, mrows, gmnrows, q0,
-             krows, vrows, wrows, k0):
-        """One (query-rows x key-rows) band; returns its dK/dV/dW."""
-        nq = qrows.shape[0]
-        nk = krows.shape[0]
-        qi = q0 + jax.lax.broadcasted_iota(jnp.int32, (nq, 1), 0)
-        ki = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, nk), 1)
-        a, ind = _recompute(qrows, krows, wrows, mrows, qi, ki,
-                            nr=nr, mode=mode, lk=lk)
-        da = jax.lax.dot_general(gyrows, vrows, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=f32)
-        da = da + gdnrows[:, None] * wrows[None, :]
-        ds = a * da + gmnrows[:, None] * ind
-        dk_b = jax.lax.dot_general(ds, qrows, (((0,), (0,)), ((), ())),
-                                   preferred_element_type=f32)   # (nk, d)
-        dv_b = jax.lax.dot_general(a, gyrows, (((0,), (0,)), ((), ())),
-                                   preferred_element_type=f32)   # (nk, dv)
-        dw_b = jnp.sum(a * gdnrows[:, None], axis=0)             # (nk,)
-        return dk_b, dv_b, dw_b
+    w = w_ref[0].astype(f32)                           # (TK, 1)
+    band = functools.partial(_band_dkvw, nr=nr, mode=mode, lk=lk)
 
     # self band: query tile `it` against this whole key tile.
     dk, dvv, dw = band(
-        qs_ref[0, 0].astype(f32), gys_ref[0, 0].astype(f32),
-        gdns_ref[0, 0].astype(f32), ms_ref[0, 0].astype(f32),
-        gmns_ref[0, 0].astype(f32), it * tq, k, v, w, it * tq)
+        *_tile_rows(qs_ref, gys_ref, gdns_ref, ms_ref, gmns_ref), it * tq,
+        k, v, w, it * tq)
 
     # prev-halo: the first nr query rows of tile it+1 read this tile's
     # last nr keys as their 'prev' band (refs are exact nr-row blocks).
     dk_h, dv_h, dw_h = band(
-        qn_ref[0, 0].astype(f32), gyn_ref[0, 0].astype(f32),
-        gdnn_ref[0, 0].astype(f32), mn_ref[0, 0].astype(f32),
-        gmnn_ref[0, 0].astype(f32), (it + 1) * tq,
-        k[tq - nr:], v[tq - nr:], w[tq - nr:], it * tq + tq - nr)
-    dk = dk + jnp.pad(dk_h, ((tq - nr, 0), (0, 0)))
-    dvv = dvv + jnp.pad(dv_h, ((tq - nr, 0), (0, 0)))
-    dw = dw + jnp.pad(dw_h, ((tq - nr, 0),))
+        *_tile_rows(qn_ref, gyn_ref, gdnn_ref, mn_ref, gmnn_ref),
+        (it + 1) * tq, k[tq - nr:], v[tq - nr:], w[tq - nr:],
+        it * tq + tq - nr)
+    dk = _add_rows(dk, dk_h, tq - nr)
+    dvv = _add_rows(dvv, dv_h, tq - nr)
+    dw = _add_rows(dw, dw_h, tq - nr)
 
     if not causal:
         # next-halo: the last nr query rows of tile it-1 read this
         # tile's first nr keys as their 'next' band.
         dk_h, dv_h, dw_h = band(
-            qp_ref[0, 0].astype(f32),
-            gyp_ref[0, 0].astype(f32),
-            gdnp_ref[0, 0].astype(f32),
-            mp_ref[0, 0].astype(f32),
-            gmnp_ref[0, 0].astype(f32), it * tq - nr,
-            k[:nr], v[:nr], w[:nr], it * tq)
-        dk = dk + jnp.pad(dk_h, ((0, tq - nr), (0, 0)))
-        dvv = dvv + jnp.pad(dv_h, ((0, tq - nr), (0, 0)))
-        dw = dw + jnp.pad(dw_h, ((0, tq - nr),))
+            *_tile_rows(qp_ref, gyp_ref, gdnp_ref, mp_ref, gmnp_ref),
+            it * tq - nr, k[:nr], v[:nr], w[:nr], it * tq)
+        dk = _add_rows(dk, dk_h, 0)
+        dvv = _add_rows(dvv, dv_h, 0)
+        dw = _add_rows(dw, dw_h, 0)
 
-    # accumulate across the (innermost) GQA group axis: the output
-    # blocks' index maps ignore g, so the block stays resident in VMEM.
-    @pl.when(g == 0)
-    def _init():
-        dk_ref[0] = dk.astype(dk_ref.dtype)
-        dv_ref[0] = dvv.astype(dv_ref.dtype)
-        dw_ref[0] = dw.astype(dw_ref.dtype)
-
-    @pl.when(g > 0)
-    def _acc():
-        dk_ref[0] += dk.astype(dk_ref.dtype)
-        dv_ref[0] += dvv.astype(dv_ref.dtype)
-        dw_ref[0] += dw.astype(dw_ref.dtype)
+    # accumulate across the (innermost) GQA group axis
+    _accumulate(g == 0, dk, dvv, dw, dk_ref, dv_ref, dw_ref)
 
 
 def _dq_sub_kernel(*refs, nr: int, ratio: int, tq: int, lk: int):
@@ -236,22 +264,18 @@ def _dq_sub_kernel(*refs, nr: int, ratio: int, tq: int, lk: int):
 
     it = pl.program_id(2)
     f32 = jnp.float32
-    q = q_ref[0, 0].astype(f32)                        # (TQ, d)
-    m = m_ref[0, 0].astype(f32)
-    gy = gy_ref[0, 0].astype(f32)
-    gdn = gdn_ref[0, 0].astype(f32)
-    gmh = gmh_ref[0, 0].astype(f32)
+    q, m, gy, gdn, gmh = _tile_rows(q_ref, m_ref, gy_ref, gdn_ref, gmh_ref)
     qi = it * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
 
     def band(k, v, w, k0):
-        k, v, w = k.astype(f32), v.astype(f32), w.astype(f32)
+        k, v, w = k.astype(f32), v.astype(f32), key_row(w)
         tk = k.shape[0]
         ki = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, tk), 1)
         a, ind = _recompute(q, k, w, m, qi, ki, nr=nr, mode=SUB_MODE,
                             lk=lk, ratio=ratio, lq=lk * ratio)
         da = jax.lax.dot_general(gy, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=f32)
-        da = da + gdn[:, None] * w[None, :]
+        da = da + gdn * w
         return a * da, ind, k
 
     if nq <= tq:
@@ -260,47 +284,12 @@ def _dq_sub_kernel(*refs, nr: int, ratio: int, tq: int, lk: int):
         bands = [band(kp_ref[0], vp_ref[0], wp_ref[0], it * tqc - nr)]
         if tqc > nr:
             bands.append(band(ks_ref[0, :tqc - nr, :], vs_ref[0, :tqc - nr, :],
-                              ws_ref[0, :tqc - nr], it * tqc))
+                              ws_ref[0, :tqc - nr, :], it * tqc))
     else:
         s_blk = nq // tq
         bands = [band(kb_ref[0], vb_ref[0], wb_ref[0],
                       (it // s_blk - 1) * nr)]
-
-    count = functools.reduce(
-        jnp.add, [ind.sum(axis=1) for _, ind, _ in bands])   # (TQ,)
-    gmn = jnp.where(count > 0, gmh / jnp.maximum(count, 1.0), 0.0)
-
-    dq = None
-    for ds0, ind, k in bands:
-        ds = ds0 + gmn[:, None] * ind
-        dqt = jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                  preferred_element_type=f32)
-        dq = dqt if dq is None else dq + dqt
-
-    dq_ref[0, 0] = dq.astype(dq_ref.dtype)
-    gmn_ref[0, 0] = gmn.astype(gmn_ref.dtype)
-
-
-def _sub_band_dkvw(qrows, gyrows, gdnrows, mrows, gmnrows, q0,
-                   krows, vrows, wrows, k0, *, nr, ratio, lk):
-    """One fine-query x coarse-key band of the sub dK/dV/dW pass."""
-    f32 = jnp.float32
-    nq_rows = qrows.shape[0]
-    nk = krows.shape[0]
-    qi = q0 + jax.lax.broadcasted_iota(jnp.int32, (nq_rows, 1), 0)
-    ki = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, nk), 1)
-    a, ind = _recompute(qrows, krows, wrows, mrows, qi, ki, nr=nr,
-                        mode=SUB_MODE, lk=lk, ratio=ratio, lq=lk * ratio)
-    da = jax.lax.dot_general(gyrows, vrows, (((1,), (1,)), ((), ())),
-                             preferred_element_type=f32)
-    da = da + gdnrows[:, None] * wrows[None, :]
-    ds = a * da + gmnrows[:, None] * ind
-    dk_b = jax.lax.dot_general(ds, qrows, (((0,), (0,)), ((), ())),
-                               preferred_element_type=f32)    # (nk, d)
-    dv_b = jax.lax.dot_general(a, gyrows, (((0,), (0,)), ((), ())),
-                               preferred_element_type=f32)    # (nk, dv)
-    dw_b = jnp.sum(a * gdnrows[:, None], axis=0)              # (nk,)
-    return dk_b, dv_b, dw_b
+    _store_dq(bands, gmh, dq_ref, gmn_ref)
 
 
 def _dkvw_sub_wide_kernel(*refs, nr: int, ratio: int, tq: int, lk: int):
@@ -322,43 +311,32 @@ def _dkvw_sub_wide_kernel(*refs, nr: int, ratio: int, tq: int, lk: int):
     tqc = tq // ratio
     k = k_ref[0].astype(f32)                           # (tqc, d)
     v = v_ref[0].astype(f32)
-    w = w_ref[0].astype(f32)
+    w = w_ref[0].astype(f32)                           # (tqc, 1)
+    band = functools.partial(_band_dkvw, nr=nr, mode=SUB_MODE, lk=lk,
+                             ratio=ratio, lq=lk * ratio)
 
     # next-halo: first nq query rows of tile it+1 x this tile's last nr
     # keys (the query refs are exact nq-row blocks, see the wide specs)
-    dk_h, dv_h, dw_h = _sub_band_dkvw(
-        qn_ref[0, 0].astype(f32), gyn_ref[0, 0].astype(f32),
-        gdnn_ref[0, 0].astype(f32), mn_ref[0, 0].astype(f32),
-        gmnn_ref[0, 0].astype(f32), (it + 1) * tq,
-        k[tqc - nr:], v[tqc - nr:], w[tqc - nr:], (it + 1) * tqc - nr,
-        nr=nr, ratio=ratio, lk=lk)
-    dk = jnp.pad(dk_h, ((tqc - nr, 0), (0, 0)))
-    dvv = jnp.pad(dv_h, ((tqc - nr, 0), (0, 0)))
-    dw = jnp.pad(dw_h, ((tqc - nr, 0),))
+    dk, dvv, dw = band(
+        *_tile_rows(qn_ref, gyn_ref, gdnn_ref, mn_ref, gmnn_ref),
+        (it + 1) * tq, k[tqc - nr:], v[tqc - nr:], w[tqc - nr:],
+        (it + 1) * tqc - nr)
 
+    # self band: query rows [nq:] of tile it x this tile's head keys;
+    # the two bands cover disjoint key rows of the tile
     if nq < tq:
-        # self band: query rows [nq:] of tile it x this tile's head keys
-        dk_s, dv_s, dw_s = _sub_band_dkvw(
-            qs_ref[0, 0, nq:, :].astype(f32), gys_ref[0, 0, nq:, :].astype(f32),
-            gdns_ref[0, 0, nq:].astype(f32), ms_ref[0, 0, nq:].astype(f32),
-            gmns_ref[0, 0, nq:].astype(f32), it * tq + nq,
-            k[:tqc - nr], v[:tqc - nr], w[:tqc - nr], it * tqc,
-            nr=nr, ratio=ratio, lk=lk)
-        dk = dk + jnp.pad(dk_s, ((0, nr), (0, 0)))
-        dvv = dvv + jnp.pad(dv_s, ((0, nr), (0, 0)))
-        dw = dw + jnp.pad(dw_s, ((0, nr),))
-
-    @pl.when(g == 0)
-    def _init():
-        dk_ref[0] = dk.astype(dk_ref.dtype)
-        dv_ref[0] = dvv.astype(dv_ref.dtype)
-        dw_ref[0] = dw.astype(dw_ref.dtype)
-
-    @pl.when(g > 0)
-    def _acc():
-        dk_ref[0] += dk.astype(dk_ref.dtype)
-        dv_ref[0] += dvv.astype(dv_ref.dtype)
-        dw_ref[0] += dw.astype(dw_ref.dtype)
+        heads = band(
+            *_tile_rows(qs_ref, gys_ref, gdns_ref, ms_ref, gmns_ref,
+                        rows=slice(nq, None)),
+            it * tq + nq, k[:tqc - nr], v[:tqc - nr], w[:tqc - nr],
+            it * tqc)
+    else:
+        heads = [jnp.zeros((tqc - nr, x.shape[1]), f32)
+                 for x in (dk, dvv, dw)]
+    if tqc > nr:
+        dk, dvv, dw = [jnp.concatenate([hd, tl], axis=0)
+                       for hd, tl in zip(heads, (dk, dvv, dw))]
+    _accumulate(g == 0, dk, dvv, dw, dk_ref, dv_ref, dw_ref)
 
 
 def _dkvw_sub_deep_kernel(*refs, nr: int, ratio: int, tq: int, lk: int):
@@ -376,24 +354,11 @@ def _dkvw_sub_deep_kernel(*refs, nr: int, ratio: int, tq: int, lk: int):
     f32 = jnp.float32
     s_blk = (nr * ratio) // tq
     q0 = ((jt + 1) * s_blk + s) * tq
-    dk, dvv, dw = _sub_band_dkvw(
-        q_ref[0, 0].astype(f32), gy_ref[0, 0].astype(f32),
-        gdn_ref[0, 0].astype(f32), m_ref[0, 0].astype(f32),
-        gmn_ref[0, 0].astype(f32), q0,
+    dk, dvv, dw = _band_dkvw(
+        *_tile_rows(q_ref, gy_ref, gdn_ref, m_ref, gmn_ref), q0,
         k_ref[0].astype(f32), v_ref[0].astype(f32), w_ref[0].astype(f32),
-        jt * nr, nr=nr, ratio=ratio, lk=lk)
-
-    @pl.when((s == 0) & (g == 0))
-    def _init():
-        dk_ref[0] = dk.astype(dk_ref.dtype)
-        dv_ref[0] = dvv.astype(dv_ref.dtype)
-        dw_ref[0] = dw.astype(dw_ref.dtype)
-
-    @pl.when((s > 0) | (g > 0))
-    def _acc():
-        dk_ref[0] += dk.astype(dk_ref.dtype)
-        dv_ref[0] += dvv.astype(dv_ref.dtype)
-        dw_ref[0] += dw.astype(dw_ref.dtype)
+        jt * nr, nr=nr, mode=SUB_MODE, lk=lk, ratio=ratio, lq=lk * ratio)
+    _accumulate((s == 0) & (g == 0), dk, dvv, dw, dk_ref, dv_ref, dw_ref)
 
 
 def band_attention_sub_bwd(q, k, v, w, y, dn, m, gy, gdn, gm, *,
@@ -413,25 +378,18 @@ def band_attention_sub_bwd(q, k, v, w, y, dn, m, gy, gdn, gm, *,
     nt = Lq // tq
     f32 = jnp.float32
 
-    gy = gy.astype(f32)
-    gdn = gdn.astype(f32)
-    gm = gm.astype(f32)
-    delta = jnp.sum(gy * y, axis=-1) + gdn * dn
-    gmh = gm - delta                                    # (B, G, Lq)
+    gy, gdn, gmh, m, w3 = _row_stats(y, dn, m, gy, gdn, gm, w)
 
     qtile_map = lambda b, g_, i: (b, g_, i, 0)
-    rtile_map = lambda b, g_, i: (b, g_, i)
 
     # ---- pass 1: dQ (fine query-tile grid) + per-row max-grad scale -------
     in_specs = [pl.BlockSpec((1, 1, tq, d), qtile_map)]
     build, layout = sub_kv_specs(nr, ratio, tq)
-    kv_specs, kv_inputs = build(k, v, w, d, dv)
+    kv_specs, kv_inputs = build(k, v, w3, d, dv)
     in_specs += kv_specs
     inputs = [q] + kv_inputs
-    in_specs += [pl.BlockSpec((1, 1, tq), rtile_map),
-                 pl.BlockSpec((1, 1, tq, dv), qtile_map),
-                 pl.BlockSpec((1, 1, tq), rtile_map),
-                 pl.BlockSpec((1, 1, tq), rtile_map)]
+    in_specs += [pl.BlockSpec((1, 1, tq, width), qtile_map)
+                 for width in (1, dv, 1, 1)]
     inputs += [m, gy, gdn, gmh]
 
     dq, gmn = launch(
@@ -439,9 +397,9 @@ def band_attention_sub_bwd(q, k, v, w, y, dn, m, gy, gdn, gm, *,
         family="sub_bwd", grid=(B, G, nt),
         in_specs=in_specs,
         out_specs=(pl.BlockSpec((1, 1, tq, d), qtile_map),
-                   pl.BlockSpec((1, 1, tq), rtile_map)),
+                   pl.BlockSpec((1, 1, tq, 1), qtile_map)),
         out_shape=(jax.ShapeDtypeStruct((B, G, Lq, d), f32),
-                   jax.ShapeDtypeStruct((B, G, Lq), f32)),
+                   jax.ShapeDtypeStruct((B, G, Lq, 1), f32)),
         operands=inputs, interpret=interpret,
         in_names=(("q",) + SUB_KV_NAMES[layout]
                   + ("m", "gy", "gdn", "gmh")),
@@ -457,26 +415,16 @@ def band_attention_sub_bwd(q, k, v, w, y, dn, m, gy, gdn, gm, *,
         nbq = Lq // nq
         tbq = tq // nq
         kv_self = lambda b, i, g_: (b, i, 0)
-        w_self = lambda b, i, g_: (b, i)
         q_self = lambda b, i, g_: (b, g_, i, 0)
         q_next = lambda b, i, g_: (
             b, g_, jnp.minimum((i + 1) * tbq, nbq - 1), 0)
-        r_self = lambda b, i, g_: (b, g_, i)
-        r_next = lambda b, i, g_: (b, g_, jnp.minimum((i + 1) * tbq, nbq - 1))
 
-        in_specs = [pl.BlockSpec((1, tqc, d), kv_self),
-                    pl.BlockSpec((1, tqc, dv), kv_self),
-                    pl.BlockSpec((1, tqc), w_self)]
-        inputs = [k, v, w]
-        for rows, mp in ((tq, q_self), (nq, q_next)):
-            in_specs.append(pl.BlockSpec((1, 1, rows, d), mp))
-            inputs.append(q)
-        for rows, mp in ((tq, q_self), (nq, q_next)):
-            in_specs.append(pl.BlockSpec((1, 1, rows, dv), mp))
-            inputs.append(gy)
-        for tensor in (gdn, m, gmn):
-            for rows, mp in ((tq, r_self), (nq, r_next)):
-                in_specs.append(pl.BlockSpec((1, 1, rows), mp))
+        in_specs = [pl.BlockSpec((1, tqc, width), kv_self)
+                    for width in (d, dv, 1)]
+        inputs = [k, v, w3]
+        for tensor, width in ((q, d), (gy, dv), (gdn, 1), (m, 1), (gmn, 1)):
+            for rows, mp in ((tq, q_self), (nq, q_next)):
+                in_specs.append(pl.BlockSpec((1, 1, rows, width), mp))
                 inputs.append(tensor)
 
         dk, dvv, dw = launch(
@@ -484,12 +432,9 @@ def band_attention_sub_bwd(q, k, v, w, y, dn, m, gy, gdn, gm, *,
                               tq=tq, lk=Lk),
             family="sub_bwd", grid=(B, nt, G),
             in_specs=in_specs,
-            out_specs=(pl.BlockSpec((1, tqc, d), kv_self),
-                       pl.BlockSpec((1, tqc, dv), kv_self),
-                       pl.BlockSpec((1, tqc), w_self)),
-            out_shape=(jax.ShapeDtypeStruct((B, Lk, d), f32),
-                       jax.ShapeDtypeStruct((B, Lk, dv), f32),
-                       jax.ShapeDtypeStruct((B, Lk), f32)),
+            out_specs=tuple(pl.BlockSpec((1, tqc, width), kv_self)
+                            for width in (d, dv, 1)),
+            out_shape=_kv_grad_shapes(B, Lk, d, dv),
             operands=inputs, interpret=interpret,
             in_names=("k", "v", "w", "q_self", "q_next",
                       "gy_self", "gy_next", "gdn_self", "gdn_next",
@@ -501,33 +446,23 @@ def band_attention_sub_bwd(q, k, v, w, y, dn, m, gy, gdn, gm, *,
         s_blk = nq // tq
         nkb = Lk // nr
         kv_blk = lambda b, j, s, g_: (b, j, 0)
-        w_blk = lambda b, j, s, g_: (b, j)
         q_map = lambda b, j, s, g_: (
             b, g_, jnp.minimum((j + 1) * s_blk + s, nt - 1), 0)
-        r_map = lambda b, j, s, g_: (
-            b, g_, jnp.minimum((j + 1) * s_blk + s, nt - 1))
 
-        in_specs = [pl.BlockSpec((1, nr, d), kv_blk),
-                    pl.BlockSpec((1, nr, dv), kv_blk),
-                    pl.BlockSpec((1, nr), w_blk),
-                    pl.BlockSpec((1, 1, tq, d), q_map),
-                    pl.BlockSpec((1, 1, tq, dv), q_map),
-                    pl.BlockSpec((1, 1, tq), r_map),
-                    pl.BlockSpec((1, 1, tq), r_map),
-                    pl.BlockSpec((1, 1, tq), r_map)]
-        inputs = [k, v, w, q, gy, gdn, m, gmn]
+        in_specs = ([pl.BlockSpec((1, nr, width), kv_blk)
+                     for width in (d, dv, 1)]
+                    + [pl.BlockSpec((1, 1, tq, width), q_map)
+                       for width in (d, dv, 1, 1, 1)])
+        inputs = [k, v, w3, q, gy, gdn, m, gmn]
 
         dk, dvv, dw = launch(
             functools.partial(_dkvw_sub_deep_kernel, nr=nr, ratio=ratio,
                               tq=tq, lk=Lk),
             family="sub_bwd", grid=(B, nkb, s_blk, G),
             in_specs=in_specs,
-            out_specs=(pl.BlockSpec((1, nr, d), kv_blk),
-                       pl.BlockSpec((1, nr, dv), kv_blk),
-                       pl.BlockSpec((1, nr), w_blk)),
-            out_shape=(jax.ShapeDtypeStruct((B, Lk, d), f32),
-                       jax.ShapeDtypeStruct((B, Lk, dv), f32),
-                       jax.ShapeDtypeStruct((B, Lk), f32)),
+            out_specs=tuple(pl.BlockSpec((1, nr, width), kv_blk)
+                            for width in (d, dv, 1)),
+            out_shape=_kv_grad_shapes(B, Lk, d, dv),
             operands=inputs, interpret=interpret,
             in_names=("k", "v", "w", "q", "gy", "gdn", "m", "gmn"),
             out_names=("dk", "dv", "dw"),
@@ -535,7 +470,28 @@ def band_attention_sub_bwd(q, k, v, w, y, dn, m, gy, gdn, gm, *,
                       layout="deep", phase="dkvw"))
 
     return (dq.astype(q.dtype), dk.astype(k.dtype),
-            dvv.astype(v.dtype), dw.astype(w.dtype))
+            dvv.astype(v.dtype), dw[..., 0].astype(w.dtype))
+
+
+def _row_stats(y, dn, m, gy, gdn, gm, w):
+    """f32 cotangents plus the max-gradient ``gmh = gm - delta``, with
+    every per-row statistic (and the key weights) as a trailing-1
+    column -- the layout whose (rows, 1) blocks Mosaic accepts for any
+    G and any nr-row halo.  ``delta_i = sum_j a_ij da_ij`` needs only
+    the saved outputs."""
+    f32 = jnp.float32
+    gy = gy.astype(f32)
+    gdn = gdn.astype(f32)
+    gm = gm.astype(f32)
+    gmh = gm - (jnp.sum(gy * y, axis=-1) + gdn * dn)
+    return gy, gdn[..., None], gmh[..., None], m[..., None], w[..., None]
+
+
+def _kv_grad_shapes(B, Lk, d, dv):
+    f32 = jnp.float32
+    return (jax.ShapeDtypeStruct((B, Lk, d), f32),
+            jax.ShapeDtypeStruct((B, Lk, dv), f32),
+            jax.ShapeDtypeStruct((B, Lk, 1), f32))
 
 
 def band_attention_bwd(
@@ -569,12 +525,7 @@ def band_attention_bwd(
     causal = mode.endswith("causal")
     f32 = jnp.float32
 
-    gy = gy.astype(f32)
-    gdn = gdn.astype(f32)
-    gm = gm.astype(f32)
-    # delta_i = sum_j a_ij da_ij, from saved outputs alone.
-    delta = jnp.sum(gy * y, axis=-1) + gdn * dn
-    gmh = gm - delta                                    # (B, G, L)
+    gy, gdn, gmh, m, w3 = _row_stats(y, dn, m, gy, gdn, gm, w)
 
     # self operands: full tiles; halo operands: exact nr-row blocks at
     # the neighbouring tile's edge (index maps count nr-row blocks)
@@ -583,32 +534,19 @@ def band_attention_bwd(
     self_map = lambda b, g_, i: (b, i, 0)
     prev_map = lambda b, g_, i: (b, jnp.maximum(i * tb - 1, 0), 0)
     next_map = lambda b, g_, i: (b, jnp.minimum((i + 1) * tb, nb - 1), 0)
-    wself_map = lambda b, g_, i: (b, i)
-    wprev_map = lambda b, g_, i: (b, jnp.maximum(i * tb - 1, 0))
-    wnext_map = lambda b, g_, i: (b, jnp.minimum((i + 1) * tb, nb - 1))
     qtile_map = lambda b, g_, i: (b, g_, i, 0)
-    rtile_map = lambda b, g_, i: (b, g_, i)
 
     # ---- pass 1: dQ (query-tile grid) + per-row max-grad scale ------------
     in_specs = [pl.BlockSpec((1, 1, tq, d), qtile_map)]
     inputs = [q]
     kmaps = [(tq, self_map), (nr, prev_map)] + (
         [] if causal else [(nr, next_map)])
-    wmaps = [(tq, wself_map), (nr, wprev_map)] + (
-        [] if causal else [(nr, wnext_map)])
-    for rows, mp in kmaps:
-        in_specs.append(pl.BlockSpec((1, rows, d), mp))
-        inputs.append(k)
-    for rows, mp in kmaps:
-        in_specs.append(pl.BlockSpec((1, rows, dv), mp))
-        inputs.append(v)
-    for rows, mp in wmaps:
-        in_specs.append(pl.BlockSpec((1, rows), mp))
-        inputs.append(w)
-    in_specs += [pl.BlockSpec((1, 1, tq), rtile_map),
-                 pl.BlockSpec((1, 1, tq, dv), qtile_map),
-                 pl.BlockSpec((1, 1, tq), rtile_map),
-                 pl.BlockSpec((1, 1, tq), rtile_map)]
+    for arr, width in ((k, d), (v, dv), (w3, 1)):
+        for rows, mp in kmaps:
+            in_specs.append(pl.BlockSpec((1, rows, width), mp))
+            inputs.append(arr)
+    in_specs += [pl.BlockSpec((1, 1, tq, width), qtile_map)
+                 for width in (1, dv, 1, 1)]
     inputs += [m, gy, gdn, gmh]
 
     halo = ("self", "prev") if causal else ("self", "prev", "next")
@@ -617,9 +555,9 @@ def band_attention_bwd(
         family="band_bwd", grid=(B, G, nt),
         in_specs=in_specs,
         out_specs=(pl.BlockSpec((1, 1, tq, d), qtile_map),
-                   pl.BlockSpec((1, 1, tq), rtile_map)),
+                   pl.BlockSpec((1, 1, tq, 1), qtile_map)),
         out_shape=(jax.ShapeDtypeStruct((B, G, L, d), f32),
-                   jax.ShapeDtypeStruct((B, G, L), f32)),
+                   jax.ShapeDtypeStruct((B, G, L, 1), f32)),
         operands=inputs, interpret=interpret,
         in_names=(("q",) + tuple(f"{a}_{h}" for a in "kvw" for h in halo)
                   + ("m", "gy", "gdn", "gmh")),
@@ -630,30 +568,17 @@ def band_attention_bwd(
     # halo query operands (the nr edge rows of the neighbouring tile)
     # are fetched as exact nr-row blocks, mirroring pass 1.
     kv_self = lambda b, i, g_: (b, i, 0)
-    w_self = lambda b, i, g_: (b, i)
     q_self = lambda b, i, g_: (b, g_, i, 0)
     q_next = lambda b, i, g_: (b, g_, jnp.minimum((i + 1) * tb, nb - 1), 0)
     q_prev = lambda b, i, g_: (b, g_, jnp.maximum(i * tb - 1, 0), 0)
-    r_self = lambda b, i, g_: (b, g_, i)
-    r_next = lambda b, i, g_: (b, g_, jnp.minimum((i + 1) * tb, nb - 1))
-    r_prev = lambda b, i, g_: (b, g_, jnp.maximum(i * tb - 1, 0))
 
     qmaps = [(tq, q_self), (nr, q_next)] + ([] if causal else [(nr, q_prev)])
-    rmaps = [(tq, r_self), (nr, r_next)] + ([] if causal else [(nr, r_prev)])
 
-    in_specs = [pl.BlockSpec((1, tq, d), kv_self),
-                pl.BlockSpec((1, tq, dv), kv_self),
-                pl.BlockSpec((1, tq), w_self)]
-    inputs = [k, v, w]
-    for rows, mp in qmaps:
-        in_specs.append(pl.BlockSpec((1, 1, rows, d), mp))
-        inputs.append(q)
-    for rows, mp in qmaps:
-        in_specs.append(pl.BlockSpec((1, 1, rows, dv), mp))
-        inputs.append(gy)
-    for tensor in (gdn, m, gmn):
-        for rows, mp in rmaps:
-            in_specs.append(pl.BlockSpec((1, 1, rows), mp))
+    in_specs = [pl.BlockSpec((1, tq, width), kv_self) for width in (d, dv, 1)]
+    inputs = [k, v, w3]
+    for tensor, width in ((q, d), (gy, dv), (gdn, 1), (m, 1), (gmn, 1)):
+        for rows, mp in qmaps:
+            in_specs.append(pl.BlockSpec((1, 1, rows, width), mp))
             inputs.append(tensor)
 
     qhalo = ("self", "next") if causal else ("self", "next", "prev")
@@ -661,12 +586,9 @@ def band_attention_bwd(
         functools.partial(_dkvw_kernel, nr=nr, mode=mode, tq=tq, lk=L),
         family="band_bwd", grid=(B, nt, G),
         in_specs=in_specs,
-        out_specs=(pl.BlockSpec((1, tq, d), kv_self),
-                   pl.BlockSpec((1, tq, dv), kv_self),
-                   pl.BlockSpec((1, tq), w_self)),
-        out_shape=(jax.ShapeDtypeStruct((B, L, d), f32),
-                   jax.ShapeDtypeStruct((B, L, dv), f32),
-                   jax.ShapeDtypeStruct((B, L), f32)),
+        out_specs=tuple(pl.BlockSpec((1, tq, width), kv_self)
+                        for width in (d, dv, 1)),
+        out_shape=_kv_grad_shapes(B, L, d, dv),
         operands=inputs, interpret=interpret,
         in_names=(("k", "v", "w")
                   + tuple(f"q_{h}" for h in qhalo)
@@ -677,4 +599,4 @@ def band_attention_bwd(
         meta=dict(mode=mode, nr=nr, tq=tq, lk=L, phase="dkvw"))
 
     return (dq.astype(q.dtype), dk.astype(k.dtype),
-            dvv.astype(v.dtype), dw.astype(w.dtype))
+            dvv.astype(v.dtype), dw[..., 0].astype(w.dtype))

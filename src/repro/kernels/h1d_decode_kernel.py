@@ -82,6 +82,23 @@ def _qz():
     return qz
 
 
+def _pairs(a):
+    """(..., L, D) level array -> (..., L/2, 2, D) sibling-pair view.
+    Mosaic refuses a 2-row block over the (L, D) dims (the second-minor
+    block dim must be a multiple of 8 or the whole dim); over the pair
+    view the block's trailing (2, D) is the whole of those dims.  For
+    f32 and bf16 levels XLA compiles the reshape to a bitcast: both
+    layouts keep each row at the same 128-lane stride."""
+    return a.reshape(*a.shape[:-2], a.shape[-2] // 2, 2, a.shape[-1])
+
+
+def _rows3(x):
+    """(R, D) per-row vectors (new rows, or a page's nr int8 scales) ->
+    (R, 1, D), so a (1, 1, D) row block's trailing dims are the array's
+    own."""
+    return x[:, None, :]
+
+
 # ---------------------------------------------------------------------------
 # fused decode attention
 # ---------------------------------------------------------------------------
@@ -116,8 +133,8 @@ def _attend_kernel(t_ref, q_ref, *refs, nr: int, nbands: int, scale: float,
         kb = k_refs[band][0].astype(f32)                 # (nr, D)
         vb = v_refs[band][0].astype(f32)                 # (nr, Dv)
         if quant and quant[band]:
-            kb = kb * ksc_refs[si][0][:, None]
-            vb = vb * vsc_refs[si][0][:, None]
+            kb = kb * ksc_refs[si][0].reshape(nr, 1)     # (1, nr) row
+            vb = vb * vsc_refs[si][0].reshape(nr, 1)
             si += 1
         s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=f32)   # (G, nr)
@@ -147,8 +164,8 @@ def _attend_kernel(t_ref, q_ref, *refs, nr: int, nbands: int, scale: float,
     a = jnp.exp(s_all - m)
     num = jax.lax.dot_general(a, v_all, (((1,), (0,)), ((), ())),
                               preferred_element_type=f32)     # (G, Dv)
-    den = jnp.sum(a * w_all, axis=-1)                    # (G,)
-    o_ref[0] = num / jnp.maximum(den, 1e-9)[:, None]
+    den = jnp.sum(a * w_all, axis=-1, keepdims=True)     # (G, 1)
+    o_ref[0] = num / jnp.maximum(den, 1e-9)
 
 
 def decode_attend_fused(cache, q: jnp.ndarray, t: jnp.ndarray, *, nr: int,
@@ -256,11 +273,11 @@ def _attend_partial_kernel(t_ref, bidx_ref, own_ref, q_ref, *refs, nr: int,
     s_all = jnp.concatenate(logits, axis=-1)             # (G, K)
     v_all = jnp.concatenate(values, axis=-2)             # (K, Dv)
     w_all = jnp.concatenate(weights, axis=-1)            # (1, K)
-    m = jnp.maximum(s_all.max(axis=-1), _MIN_M)          # (G,)
-    a = jnp.exp(s_all - m[:, None])
+    m = jnp.maximum(s_all.max(axis=-1, keepdims=True), _MIN_M)   # (G, 1)
+    a = jnp.exp(s_all - m)
     num_ref[0] = jax.lax.dot_general(a, v_all, (((1,), (0,)), ((), ())),
                                      preferred_element_type=f32)
-    den_ref[0] = jnp.sum(a * w_all, axis=-1)
+    den_ref[0] = jnp.sum(a * w_all, axis=-1, keepdims=True)
     m_ref[0] = m
 
 
@@ -307,17 +324,17 @@ def decode_attend_partial(cache, q: jnp.ndarray, t: jnp.ndarray,
                        dtype=np.int32)
     Lloc = cache.k.shape[-2]
     bn = _band_names(nbands)
-    return launch(
+    row_map = lambda r, tref, bref, oref: (r, 0, 0)
+    num, den, m = launch(
         kernel, family="decode_attend_partial", grid=(R,),
         in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((1, G, Dv), lambda r, tref, bref, oref: (r, 0, 0)),
-            pl.BlockSpec((1, G), lambda r, tref, bref, oref: (r, 0)),
-            pl.BlockSpec((1, G), lambda r, tref, bref, oref: (r, 0)),
-        ),
+        # den/m as (G, 1) columns: a (1, G) block over (R, G) is refused
+        out_specs=(pl.BlockSpec((1, G, Dv), row_map),
+                   pl.BlockSpec((1, G, 1), row_map),
+                   pl.BlockSpec((1, G, 1), row_map)),
         out_shape=(jax.ShapeDtypeStruct((R, G, Dv), f32),
-                   jax.ShapeDtypeStruct((R, G), f32),
-                   jax.ShapeDtypeStruct((R, G), f32)),
+                   jax.ShapeDtypeStruct((R, G, 1), f32),
+                   jax.ShapeDtypeStruct((R, G, 1), f32)),
         operands=[q, *k_arrs, *v_arrs],
         scalars=(t.astype(jnp.int32), bidx.astype(jnp.int32),
                  owned.astype(jnp.int32)),
@@ -328,6 +345,7 @@ def decode_attend_partial(cache, q: jnp.ndarray, t: jnp.ndarray,
         out_names=("num", "den", "m"), interpret=interpret,
         meta=dict(nr=nr, Lloc=Lloc, levels=levels,
                   band_levels=_band_levels(nbands)))
+    return num, den[..., 0], m[..., 0]
 
 
 # ---------------------------------------------------------------------------
@@ -346,14 +364,14 @@ def _update_kernel(t_ref, knew_ref, vnew_ref, *refs, nlev: int):
     f32 = jnp.float32
     sel_row = jax.lax.broadcasted_iota(jnp.int32, (2, 1), 0)
 
-    new_k = knew_ref[...].astype(f32)                    # (1, D)
-    new_v = vnew_ref[...].astype(f32)                    # (1, Dv)
+    new_k = knew_ref[0].astype(f32)                      # (1, D)
+    new_v = vnew_ref[0].astype(f32)                      # (1, Dv)
     for l in range(nlev):
         sel = sel_row == ((t >> l) & 1)
-        pk = jnp.where(sel, new_k, in_refs[2 * l][0].astype(f32))
-        pv = jnp.where(sel, new_v, in_refs[2 * l + 1][0].astype(f32))
-        out_refs[2 * l][0] = pk.astype(out_refs[2 * l].dtype)
-        out_refs[2 * l + 1][0] = pv.astype(out_refs[2 * l + 1].dtype)
+        pk = jnp.where(sel, new_k, in_refs[2 * l][0, 0].astype(f32))
+        pv = jnp.where(sel, new_v, in_refs[2 * l + 1][0, 0].astype(f32))
+        out_refs[2 * l][0, 0] = pk.astype(out_refs[2 * l].dtype)
+        out_refs[2 * l + 1][0, 0] = pv.astype(out_refs[2 * l + 1].dtype)
         if l + 1 < nlev:
             new_k = pk.mean(axis=0, keepdims=True)       # Eq. 25/26
             new_v = pv.sum(axis=0, keepdims=True)        # Eq. 27
@@ -378,32 +396,40 @@ def update_cache_fused(cache, k_new: jnp.ndarray, v_new: jnp.ndarray,
         npairs = ka.shape[-2] // 2
 
         def pair_map(r, tref, l=l, npairs=npairs):
-            return (r, jnp.minimum(tref[r] >> (l + 1), npairs - 1), 0)
+            return (r, jnp.minimum(tref[r] >> (l + 1), npairs - 1), 0, 0)
 
         for a, d_ in ((ka, D), (va, Dv)):
-            arrs.append(a)
-            in_specs.append(pl.BlockSpec((1, 2, d_), pair_map))
-            out_specs.append(pl.BlockSpec((1, 2, d_), pair_map))
-            out_shape.append(jax.ShapeDtypeStruct(a.shape, a.dtype))
+            arrs.append(_pairs(a))
+            in_specs.append(pl.BlockSpec((1, 1, 2, d_), pair_map))
+            out_specs.append(pl.BlockSpec((1, 1, 2, d_), pair_map))
+            out_shape.append(jax.ShapeDtypeStruct(arrs[-1].shape, a.dtype))
 
     # alias each cache operand to its output (operand-indexed; launch()
     # translates to pallas call-arg indices past the scalar args)
     aliases = {2 + i: i for i in range(2 * nlev)}
     kernel = functools.partial(_update_kernel, nlev=nlev)
     lvl_names = [f"{a}_l{l}" for l in range(nlev) for a in ("k", "v")]
+    row_map = lambda r, tref: (r, 0, 0)
     outs = launch(
         kernel, family="decode_update", grid=(R,),
-        in_specs=[pl.BlockSpec((1, D), lambda r, tref: (r, 0)),
-                  pl.BlockSpec((1, Dv), lambda r, tref: (r, 0))] + in_specs,
+        in_specs=[pl.BlockSpec((1, 1, D), row_map),
+                  pl.BlockSpec((1, 1, Dv), row_map)] + in_specs,
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
-        operands=[k_new, v_new, *arrs],
+        operands=[_rows3(k_new), _rows3(v_new), *arrs],
         scalars=(t.astype(jnp.int32),),
         scalar_bounds=((0, Lmax - 1),),
         scalar_names=("t",),
         in_names=["k_new", "v_new"] + lvl_names,
         out_names=lvl_names, aliases=aliases, interpret=interpret,
         meta=dict(Lmax=Lmax, nlev=nlev))
+    return _unpair(cache, outs, nlev)
+
+
+def _unpair(cache, outs, nlev):
+    """Rebuild a cache of ``cache``'s type from the pair-view outputs
+    (k_l0, v_l0, k_l1, v_l1, ...) of an update launch."""
+    outs = [o.reshape(*o.shape[:-3], -1, o.shape[-1]) for o in outs]
     ck = tuple(outs[2 + 2 * i] for i in range(nlev - 1))
     cv = tuple(outs[3 + 2 * i] for i in range(nlev - 1))
     return type(cache)(k=outs[0], v=outs[1], ck=ck, cv=cv)
@@ -488,7 +514,7 @@ def decode_attend_paged_quant(pool, q: jnp.ndarray, t: jnp.ndarray,
     ``pool`` is a ``core.h1d_decode.QuantPagedH1DCache``: int8 pages for
     any subset of levels, with per-row f32 scales ``(NP_l, nr)``.  The
     scales ride the SAME scalar-prefetched ``bidx`` indirection as the
-    pages -- one extra ``(1, nr)`` scale block per quantized band, whose
+    pages -- one extra ``(1, 1, nr)`` scale block per quantized band, whose
     index map reads the identical table column -- and the dequantize
     (one multiply per gathered row) happens in VMEM right before the
     QK^T dot.  Still one launch on the (R,) grid; fp32 levels of a
@@ -510,7 +536,7 @@ def decode_attend_paged_quant(pool, q: jnp.ndarray, t: jnp.ndarray,
         return lambda r, tref, bref: (bref[r, band], 0, 0)
 
     def band_map_sc(band):
-        return lambda r, tref, bref: (bref[r, band], 0)
+        return lambda r, tref, bref: (bref[r, band], 0, 0)
 
     maps = [band_map(b) for b in range(nbands)]
     k_arrs = [pool.k, pool.k] + list(pool.ck)
@@ -521,8 +547,8 @@ def decode_attend_paged_quant(pool, q: jnp.ndarray, t: jnp.ndarray,
     for scs in (ksc_all, vsc_all):         # k-scales first, then v-scales
         for b in range(nbands):
             if quant[b]:
-                sc_arrs.append(scs[b])
-                sc_specs.append(pl.BlockSpec((1, nr), band_map_sc(b)))
+                sc_arrs.append(_rows3(scs[b]))
+                sc_specs.append(pl.BlockSpec((1, 1, nr), band_map_sc(b)))
 
     in_specs = [pl.BlockSpec((1, G, D), lambda r, tref, bref: (r, 0, 0))]
     in_specs += [pl.BlockSpec((1, nr, D), mp) for mp in maps]
@@ -588,15 +614,15 @@ def update_cache_paged(pool, k_new: jnp.ndarray, v_new: jnp.ndarray,
     for l, (ka, va) in enumerate(lvls):
 
         def pair_map(r, tref, uref, l=l):
-            return (uref[r, l], (tref[r] >> (l + 1)) & (nr // 2 - 1), 0)
+            return (uref[r, l], (tref[r] >> (l + 1)) & (nr // 2 - 1), 0, 0)
 
         for a, d_ in ((ka, D), (va, Dv)):
-            arrs.append(a)
-            in_specs.append(pl.BlockSpec((1, 2, d_), pair_map))
-            out_specs.append(pl.BlockSpec((1, 2, d_), pair_map))
-            out_shape.append(jax.ShapeDtypeStruct(a.shape, a.dtype))
+            arrs.append(_pairs(a))
+            in_specs.append(pl.BlockSpec((1, 1, 2, d_), pair_map))
+            out_specs.append(pl.BlockSpec((1, 1, 2, d_), pair_map))
+            out_shape.append(jax.ShapeDtypeStruct(arrs[-1].shape, a.dtype))
 
-    row_map = lambda r, tref, uref: (r, 0)
+    row_map = lambda r, tref, uref: (r, 0, 0)
     # aliases are operand-indexed ((k_new, v_new, *arrs): pool operands
     # start at 2); launch() shifts past the scalar args.
     aliases = {2 + i: i for i in range(2 * nlev)}
@@ -607,25 +633,23 @@ def update_cache_paged(pool, k_new: jnp.ndarray, v_new: jnp.ndarray,
     lvl_names = [f"{a}_l{l}" for l in range(nlev) for a in ("k", "v")]
     outs = launch(
         kernel, family="decode_update_paged", grid=(R,),
-        in_specs=[pl.BlockSpec((1, D), row_map),
-                  pl.BlockSpec((1, Dv), row_map)] + in_specs,
+        in_specs=[pl.BlockSpec((1, 1, D), row_map),
+                  pl.BlockSpec((1, 1, Dv), row_map)] + in_specs,
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
-        operands=[k_new, v_new, *arrs],
+        operands=[_rows3(k_new), _rows3(v_new), *arrs],
         scalars=(t.astype(jnp.int32), utab.astype(jnp.int32)),
         scalar_bounds=((0, _T_MAX), (0, utab_hi)),
         scalar_names=("t", "utab"),
         in_names=["k_new", "v_new"] + lvl_names,
         out_names=lvl_names, aliases=aliases, interpret=interpret,
         meta=dict(nr=nr, nlev=nlev))
-    ck = tuple(outs[2 + 2 * i] for i in range(nlev - 1))
-    cv = tuple(outs[3 + 2 * i] for i in range(nlev - 1))
-    return type(pool)(k=outs[0], v=outs[1], ck=ck, cv=cv)
+    return _unpair(pool, outs, nlev)
 
 
 def _update_paged_quant_kernel(t_ref, utab_ref, knew_ref, vnew_ref, *refs,
-                               nlev: int, quant, qmax: float, recip: float,
-                               eps: float):
+                               nlev: int, nr: int, quant, qmax: float,
+                               recip: float, eps: float):
     """Quantized variant of :func:`_update_kernel`: at each int8 level
     the 2-row sibling pair is dequantized with its per-row scales, the
     new row substituted, and the pair REquantized in place (fresh absmax
@@ -642,17 +666,32 @@ def _update_paged_quant_kernel(t_ref, utab_ref, knew_ref, vnew_ref, *refs,
     t = t_ref[r]
     f32 = jnp.float32
     sel_row = jax.lax.broadcasted_iota(jnp.int32, (2, 1), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, nr), 1)
 
-    new_k = knew_ref[...].astype(f32)                    # (1, D)
-    new_v = vnew_ref[...].astype(f32)                    # (1, Dv)
+    new_k = knew_ref[0].astype(f32)                      # (1, D)
+    new_v = vnew_ref[0].astype(f32)                      # (1, Dv)
     si = 0
     for l in range(nlev):
         sel = sel_row == ((t >> l) & 1)
-        kd = in_data[2 * l][0].astype(f32)               # (2, D)
-        vd = in_data[2 * l + 1][0].astype(f32)
+        kd = in_data[2 * l][0, 0].astype(f32)            # (2, D)
+        vd = in_data[2 * l + 1][0, 0].astype(f32)
         if quant[l]:
-            kd = kd * in_sc[2 * si][0][:, None]
-            vd = vd * in_sc[2 * si + 1][0][:, None]
+            # the page's (1, nr) scale row; the pair is lanes 2p, 2p+1
+            p2 = 2 * ((t >> (l + 1)) & (nr // 2 - 1))
+            at0, at1 = lane == p2, lane == p2 + 1
+
+            def pair_col(row):          # (1, nr) -> the pair's (2, 1)
+                s0 = jnp.sum(jnp.where(at0, row, 0.0), axis=1, keepdims=True)
+                s1 = jnp.sum(jnp.where(at1, row, 0.0), axis=1, keepdims=True)
+                return jnp.where(sel_row == 0, s0, s1)
+
+            def put_pair(row, col):     # write the pair's (2, 1) back
+                return jnp.where(at0, col[0:1], jnp.where(at1, col[1:2], row))
+
+            ksc_row = in_sc[2 * si][0]
+            vsc_row = in_sc[2 * si + 1][0]
+            kd = kd * pair_col(ksc_row)
+            vd = vd * pair_col(vsc_row)
         pk = jnp.where(sel, new_k, kd)
         pv = jnp.where(sel, new_v, vd)
         if quant[l]:
@@ -660,16 +699,16 @@ def _update_paged_quant_kernel(t_ref, utab_ref, knew_ref, vnew_ref, *refs,
                               eps) * recip
             vsc = jnp.maximum(jnp.max(jnp.abs(pv), axis=1, keepdims=True),
                               eps) * recip
-            out_data[2 * l][0] = jnp.clip(jnp.round(pk / ksc),
-                                          -qmax, qmax).astype(jnp.int8)
-            out_data[2 * l + 1][0] = jnp.clip(jnp.round(pv / vsc),
-                                              -qmax, qmax).astype(jnp.int8)
-            out_sc[2 * si][0] = ksc[:, 0]
-            out_sc[2 * si + 1][0] = vsc[:, 0]
+            out_data[2 * l][0, 0] = jnp.clip(jnp.round(pk / ksc),
+                                             -qmax, qmax).astype(jnp.int8)
+            out_data[2 * l + 1][0, 0] = jnp.clip(jnp.round(pv / vsc),
+                                                 -qmax, qmax).astype(jnp.int8)
+            out_sc[2 * si][0] = put_pair(ksc_row, ksc)
+            out_sc[2 * si + 1][0] = put_pair(vsc_row, vsc)
             si += 1
         else:
-            out_data[2 * l][0] = pk.astype(out_data[2 * l].dtype)
-            out_data[2 * l + 1][0] = pv.astype(out_data[2 * l + 1].dtype)
+            out_data[2 * l][0, 0] = pk.astype(out_data[2 * l].dtype)
+            out_data[2 * l + 1][0, 0] = pv.astype(out_data[2 * l + 1].dtype)
         if l + 1 < nlev:
             new_k = pk.mean(axis=0, keepdims=True)       # Eq. 25/26
             new_v = pv.sum(axis=0, keepdims=True)        # Eq. 27
@@ -682,10 +721,11 @@ def update_cache_paged_quant(pool, k_new: jnp.ndarray, v_new: jnp.ndarray,
     (``core.h1d_decode.QuantPagedH1DCache``).
 
     Same single-launch in-place scatter as :func:`update_cache_paged`;
-    each quantized level additionally carries a ``(1, 2)`` per-row scale
-    block whose index map reads the SAME ``utab`` column / pair index as
+    each quantized level additionally carries its page's ``(1, 1, nr)``
+    per-row scale row, whose index map reads the SAME ``utab`` column as
     its data block, aliased input->output so both the int8 pair and its
-    two scales rewrite in place.  fp32 levels of a mixed pool pass their
+    two scales (lanes ``2p, 2p+1`` of the row; the others pass through
+    unchanged) rewrite in place.  fp32 levels of a mixed pool pass their
     scale arrays through untouched (never kernel operands)."""
     qz = _qz()
     R, D = k_new.shape
@@ -702,29 +742,33 @@ def update_cache_paged_quant(pool, k_new: jnp.ndarray, v_new: jnp.ndarray,
     for l, (ka, va, ksa, vsa) in enumerate(lvls):
 
         def pair_map(r, tref, uref, l=l):
-            return (uref[r, l], (tref[r] >> (l + 1)) & (nr // 2 - 1), 0)
+            return (uref[r, l], (tref[r] >> (l + 1)) & (nr // 2 - 1), 0, 0)
 
-        def pair_map_sc(r, tref, uref, l=l):
-            return (uref[r, l], (tref[r] >> (l + 1)) & (nr // 2 - 1))
+        # a page's whole (1, nr) scale row: a (1, 2) pair block over
+        # (NP, nr) is refused, and pages are private to their writer
+        def page_map_sc(r, tref, uref, l=l):
+            return (uref[r, l], 0, 0)
 
         for a, d_ in ((ka, D), (va, Dv)):
-            data_arrs.append(a)
-            data_in.append(pl.BlockSpec((1, 2, d_), pair_map))
-            data_out.append(pl.BlockSpec((1, 2, d_), pair_map))
-            data_shape.append(jax.ShapeDtypeStruct(a.shape, a.dtype))
+            data_arrs.append(_pairs(a))
+            data_in.append(pl.BlockSpec((1, 1, 2, d_), pair_map))
+            data_out.append(pl.BlockSpec((1, 1, 2, d_), pair_map))
+            data_shape.append(jax.ShapeDtypeStruct(data_arrs[-1].shape,
+                                                   a.dtype))
         if quant[l]:
             for a in (ksa, vsa):
-                sc_arrs.append(a)
-                sc_in.append(pl.BlockSpec((1, 2), pair_map_sc))
-                sc_out.append(pl.BlockSpec((1, 2), pair_map_sc))
-                sc_shape.append(jax.ShapeDtypeStruct(a.shape, a.dtype))
+                sc_arrs.append(_rows3(a))
+                sc_in.append(pl.BlockSpec((1, 1, nr), page_map_sc))
+                sc_out.append(pl.BlockSpec((1, 1, nr), page_map_sc))
+                sc_shape.append(jax.ShapeDtypeStruct(sc_arrs[-1].shape,
+                                                     a.dtype))
 
-    row_map = lambda r, tref, uref: (r, 0)
+    row_map = lambda r, tref, uref: (r, 0, 0)
     # (k_new, v_new, *data_arrs, *sc_arrs): every pool operand (payload
     # AND scale blocks) aliases its mirror output; operand-indexed.
     nio = 2 * nlev + 2 * sum(quant)
     aliases = {2 + i: i for i in range(nio)}
-    kernel = functools.partial(_update_paged_quant_kernel, nlev=nlev,
+    kernel = functools.partial(_update_paged_quant_kernel, nlev=nlev, nr=nr,
                                quant=quant, qmax=qz.QMAX,
                                recip=qz.RECIP_QMAX, eps=qz.EPS)
     utab_hi = np.array([ka.shape[0] - 1 for ka, _, _, _ in lvls],
@@ -734,11 +778,11 @@ def update_cache_paged_quant(pool, k_new: jnp.ndarray, v_new: jnp.ndarray,
                 if quant[l]]
     outs = launch(
         kernel, family="decode_update_paged_quant", grid=(R,),
-        in_specs=[pl.BlockSpec((1, D), row_map),
-                  pl.BlockSpec((1, Dv), row_map)] + data_in + sc_in,
+        in_specs=[pl.BlockSpec((1, 1, D), row_map),
+                  pl.BlockSpec((1, 1, Dv), row_map)] + data_in + sc_in,
         out_specs=tuple(data_out + sc_out),
         out_shape=tuple(data_shape + sc_shape),
-        operands=[k_new, v_new, *data_arrs, *sc_arrs],
+        operands=[_rows3(k_new), _rows3(v_new), *data_arrs, *sc_arrs],
         scalars=(t.astype(jnp.int32), utab.astype(jnp.int32)),
         scalar_bounds=((0, _T_MAX), (0, utab_hi)),
         scalar_names=("t", "utab"),
@@ -746,8 +790,9 @@ def update_cache_paged_quant(pool, k_new: jnp.ndarray, v_new: jnp.ndarray,
         out_names=lvl_names + sc_names, aliases=aliases,
         interpret=interpret,
         meta=dict(nr=nr, nlev=nlev, quant=quant))
-    data = outs[:2 * nlev]
-    scs = outs[2 * nlev:]
+    data = [o.reshape(*o.shape[:-3], -1, o.shape[-1])
+            for o in outs[:2 * nlev]]
+    scs = [o[:, 0, :] for o in outs[2 * nlev:]]
     ksc_out, vsc_out = [], []
     all_ks = [pool.ksc] + list(pool.cksc)
     all_vs = [pool.vsc] + list(pool.cvsc)
@@ -788,20 +833,20 @@ def _update_partial_kernel(t_ref, own_ref, knew_ref, vnew_ref, *refs,
     f32 = jnp.float32
     sel_row = jax.lax.broadcasted_iota(jnp.int32, (2, 1), 0)
 
-    new_k = knew_ref[...].astype(f32)                    # (1, D)
-    new_v = vnew_ref[...].astype(f32)                    # (1, Dv)
+    new_k = knew_ref[0].astype(f32)                      # (1, D)
+    new_v = vnew_ref[0].astype(f32)                      # (1, Dv)
     for l in range(nlev):
         sel = (sel_row == ((t >> l) & 1)) & owned
-        pk = jnp.where(sel, new_k, in_refs[2 * l][0].astype(f32))
-        pv = jnp.where(sel, new_v, in_refs[2 * l + 1][0].astype(f32))
-        out_refs[2 * l][0] = pk.astype(out_refs[2 * l].dtype)
-        out_refs[2 * l + 1][0] = pv.astype(out_refs[2 * l + 1].dtype)
+        pk = jnp.where(sel, new_k, in_refs[2 * l][0, 0].astype(f32))
+        pv = jnp.where(sel, new_v, in_refs[2 * l + 1][0, 0].astype(f32))
+        out_refs[2 * l][0, 0] = pk.astype(out_refs[2 * l].dtype)
+        out_refs[2 * l + 1][0, 0] = pv.astype(out_refs[2 * l + 1].dtype)
         new_k = pk.mean(axis=0, keepdims=True)
         new_v = pv.sum(axis=0, keepdims=True)
     # carried row for the first level ABOVE this sharded chain; garbage
     # on non-owner rows (the caller masks it with `owned` before psum)
-    ck_ref[...] = new_k.astype(ck_ref.dtype)
-    cv_ref[...] = new_v.astype(cv_ref.dtype)
+    ck_ref[0] = new_k.astype(ck_ref.dtype)
+    cv_ref[0] = new_v.astype(cv_ref.dtype)
 
 
 def update_cache_partial(cache, k_new: jnp.ndarray, v_new: jnp.ndarray,
@@ -828,19 +873,19 @@ def update_cache_partial(cache, k_new: jnp.ndarray, v_new: jnp.ndarray,
         npairs = ka.shape[-2] // 2
 
         def pair_map(r, tref, oref, l=l, npairs=npairs):
-            return (r, jnp.minimum(tref[r] >> (l + 1), npairs - 1), 0)
+            return (r, jnp.minimum(tref[r] >> (l + 1), npairs - 1), 0, 0)
 
         for a, d_ in ((ka, D), (va, Dv)):
-            arrs.append(a)
-            in_specs.append(pl.BlockSpec((1, 2, d_), pair_map))
-            out_specs.append(pl.BlockSpec((1, 2, d_), pair_map))
-            out_shape.append(jax.ShapeDtypeStruct(a.shape, a.dtype))
+            arrs.append(_pairs(a))
+            in_specs.append(pl.BlockSpec((1, 1, 2, d_), pair_map))
+            out_specs.append(pl.BlockSpec((1, 1, 2, d_), pair_map))
+            out_shape.append(jax.ShapeDtypeStruct(arrs[-1].shape, a.dtype))
 
-    row_map = lambda r, tref, oref: (r, 0)
-    out_specs += [pl.BlockSpec((1, D), row_map),
-                  pl.BlockSpec((1, Dv), row_map)]
-    out_shape += [jax.ShapeDtypeStruct((R, D), cache.k.dtype),
-                  jax.ShapeDtypeStruct((R, Dv), cache.v.dtype)]
+    row_map = lambda r, tref, oref: (r, 0, 0)
+    out_specs += [pl.BlockSpec((1, 1, D), row_map),
+                  pl.BlockSpec((1, 1, Dv), row_map)]
+    out_shape += [jax.ShapeDtypeStruct((R, 1, D), cache.k.dtype),
+                  jax.ShapeDtypeStruct((R, 1, Dv), cache.v.dtype)]
 
     # (k_new, v_new, *arrs): cache operands start at operand index 2;
     # the two carry outputs at the end are not aliased.
@@ -850,11 +895,11 @@ def update_cache_partial(cache, k_new: jnp.ndarray, v_new: jnp.ndarray,
     lvl_names = [f"{a}_l{l}" for l in range(nlev) for a in ("k", "v")]
     outs = launch(
         kernel, family="decode_update_partial", grid=(R,),
-        in_specs=[pl.BlockSpec((1, D), row_map),
-                  pl.BlockSpec((1, Dv), row_map)] + in_specs,
+        in_specs=[pl.BlockSpec((1, 1, D), row_map),
+                  pl.BlockSpec((1, 1, Dv), row_map)] + in_specs,
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
-        operands=[k_new, v_new, *arrs],
+        operands=[_rows3(k_new), _rows3(v_new), *arrs],
         scalars=(t_loc.astype(jnp.int32), owned.astype(jnp.int32)),
         scalar_bounds=((0, Lloc - 1 if t_hi is None else t_hi), (0, 1)),
         scalar_names=("t_loc", "owned"),
@@ -862,7 +907,5 @@ def update_cache_partial(cache, k_new: jnp.ndarray, v_new: jnp.ndarray,
         out_names=lvl_names + ["carry_k", "carry_v"],
         aliases=aliases, interpret=interpret,
         meta=dict(Lloc=Lloc, nlev=nlev))
-    ck = tuple(outs[2 + 2 * i] for i in range(nlev - 1))
-    cv = tuple(outs[3 + 2 * i] for i in range(nlev - 1))
-    upd = type(cache)(k=outs[0], v=outs[1], ck=ck, cv=cv)
-    return upd, outs[2 * nlev], outs[2 * nlev + 1]
+    upd = _unpair(cache, outs[:2 * nlev], nlev)
+    return upd, outs[2 * nlev][:, 0], outs[2 * nlev + 1][:, 0]

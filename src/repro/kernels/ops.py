@@ -187,13 +187,16 @@ def band_attention(
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Banded block attention for one hierarchy level.  See module doc."""
     policy = tuning.get_policy()
-    impl = policy.resolve_impl(impl)
+    family = "sub_fwd" if mode == h1d_block.SUB_MODE else "band_fwd"
+    impl = policy.resolve_impl(impl, family)
     L = q.shape[-2]
     if impl == "jnp":
         if mode == h1d_block.SUB_MODE:
             return _blocked_sub_jnp(q, k, v, w, nr=nr, ratio=ratio)
         return _blocked_jnp(q, k, v, w, nr=nr, mode=mode)
-    # impl is 'pallas' or 'pallas_interpret' (the enum admits nothing else)
+    # impl is 'pallas' or 'pallas_interpret' (the enum admits nothing
+    # else); the log entry covers the custom VJP's backward kernels too
+    policy.note_launch(family, impl=impl, grid="tiles")
     ctx = _sp_ctx()
     if ctx is not None and _sp_shardable(L, ctx, nr, mode, ratio):
         from repro.parallel.sp_attention import sp_band_attention

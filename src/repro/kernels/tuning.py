@@ -12,9 +12,10 @@ resolves through one :class:`KernelPolicy` object (DESIGN.md section
   bypasses tuning entirely (it is still legalized by
   :func:`resolve_tq` and validated by :func:`canonical_impl`).
 * **Table**: a versioned JSON tuning table under
-  ``~/.cache/repro_tune/<backend>/<family>.json`` (override the root
-  with ``$REPRO_TUNE_CACHE``), keyed by shape bucket + dtype + mode and
-  written by the measured :meth:`KernelPolicy.autotune_band` pass.
+  ``.repro_tune/<backend>/<family>.json`` at the checkout root (git
+  ignored; override the root with ``$REPRO_TUNE_CACHE``), keyed by
+  shape bucket + dtype + mode and written by the measured
+  :meth:`KernelPolicy.autotune_band` pass.
   Corrupt / stale / version-mismatched files fall back to the defaults
   with a ``RuntimeWarning`` -- never a crash, never silent.
 * **Defaults**: a deterministic table committed with the source
@@ -22,10 +23,11 @@ resolves through one :class:`KernelPolicy` object (DESIGN.md section
   ever runs implicitly.
 
 ``impl='auto'`` picks the backend-appropriate implementation: the fused
-Pallas kernels on TPU/GPU, the blocked-XLA program on CPU (where it is
-both the gradient/decode oracle and the fast path; the interpreted
-kernels remain an explicit opt-in for CI parity).  Unknown impl strings
-raise ``ValueError`` listing :data:`IMPLS`.
+Pallas kernels on TPU, the blocked-XLA program on every other backend
+(on CPU it is both the gradient/decode oracle and the fast path; the
+interpreted kernels remain an explicit opt-in for CI parity).  The
+kernels are Mosaic (TPU) kernels: no backend but ``tpu`` resolves to
+them.  Unknown impl strings raise ``ValueError`` listing :data:`IMPLS`.
 
 Every resolution is appended to an in-process decision log
 (``policy.decisions``) so tests and benchmarks can assert which config
@@ -67,6 +69,10 @@ FAMILIES = (
 TABLE_VERSION = 1
 _DEFAULTS_PATH = os.path.join(os.path.dirname(__file__),
                               "tuning_defaults.json")
+# <checkout>/.repro_tune: a run depends only on the tree it runs from
+DEFAULT_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".repro_tune"))
 _SUB = "sub"
 
 
@@ -173,8 +179,8 @@ class KernelPolicy:
                 f"usable path; using the default cache dir",
                 RuntimeWarning)
             env_dir = None
-        self.cache_dir = (cache_dir or env_dir
-                          or os.path.expanduser("~/.cache/repro_tune"))
+        self.cache_dir = os.path.normpath(
+            cache_dir or env_dir or DEFAULT_CACHE_DIR)
         self.defaults = _load_defaults(defaults_path)
         self._tables: Dict[str, Dict[str, Any]] = {}
         self._memo: Dict[Tuple[str, str], Tuple[Dict[str, Any], str]] = {}
@@ -184,22 +190,21 @@ class KernelPolicy:
 
     def resolve_impl(self, impl: str, family: str = "band") -> str:
         """Canonicalize ``impl`` and resolve ``'auto'`` to the backend
-        default: fused Pallas kernels on TPU/GPU, blocked XLA on CPU
+        default: fused Pallas kernels on TPU, blocked XLA elsewhere
         (the oracle path, which doubles as the fast CPU path)."""
         impl = canonical_impl(impl)
         if impl != "auto":
             return impl
-        resolved = "pallas" if self.backend in ("tpu", "gpu") else "jnp"
+        resolved = "pallas" if self.backend == "tpu" else "jnp"
         self._log(family, f"impl@{self.backend}", "auto",
                   {"impl": resolved})
         return resolved
 
     def kernel_impl(self) -> str:
         """The impl that exercises the fused kernel *bodies* on this
-        backend (what the autotuner measures): compiled on TPU/GPU,
-        interpreted on CPU."""
-        return "pallas" if self.backend in ("tpu", "gpu") else \
-            "pallas_interpret"
+        backend (what the autotuner measures): compiled on TPU,
+        interpreted elsewhere."""
+        return "pallas" if self.backend == "tpu" else "pallas_interpret"
 
     # -- candidate enumeration ----------------------------------------------
 

@@ -9,27 +9,18 @@ from the actual TPU topology.
 from __future__ import annotations
 
 import jax
-
-from repro.parallel.sharding import axis_type_kwargs
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **axis_type_kwargs(len(axes)))
-
-
-def use_mesh(mesh):
-    """Version-compat mesh context: ``jax.set_mesh`` (jax >= 0.6) or the
-    ``Mesh`` object's own context manager (0.4.x)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 # TPU v5e-ish hardware constants used by the roofline analysis

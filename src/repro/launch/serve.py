@@ -16,6 +16,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch import compile_cache
 from repro.models import get_model
 from repro.serve import ServeEngine, Request
 
@@ -79,6 +80,7 @@ def main(argv=None):
     ap.add_argument("--metrics-period", type=float, default=10.0,
                     help="--metrics-jsonl emission period in seconds")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     from repro import obs
     telemetry = (args.telemetry or args.trace_out or args.prom_out

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, get_smoke_config
 from repro.data import ZipfLM, HierarchicalLM, Prefetcher
+from repro.launch import compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import set_mesh_axes
 from repro.parallel import param_shardings
@@ -61,6 +62,7 @@ def main(argv=None):
                     help="write a Chrome trace-event JSON "
                          "(Perfetto-loadable) at exit")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     from repro import obs
     if args.telemetry or args.trace_out:
@@ -84,8 +86,7 @@ def main(argv=None):
                    batch_per_host=args.batch, seed=args.seed)
 
     key = jax.random.PRNGKey(args.seed)
-    from repro.launch.mesh import use_mesh
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         state, specs = init_state(key, cfg, tc)
         psh = param_shardings(mesh, specs)
         state = TrainState(

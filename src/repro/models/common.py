@@ -39,13 +39,14 @@ class ModelConfig:
     attention: str = "h1d"       # h1d | full | paper's baseline comparison
     nr: int = 16                 # N_r, the paper's single hyper-parameter
     causal_mode: str = "fine-q"  # fine-q (leak-free) | coarse-q (paper-faithful)
-    attn_impl: str = "jnp"       # auto | jnp | pallas | pallas_interpret
-                                 # ('auto': kernels.tuning.KernelPolicy
-                                 # resolves per backend)
+    attn_impl: str = "auto"      # auto | jnp | pallas | pallas_interpret
+                                 # ('auto': the fused kernels on TPU, the
+                                 # blocked XLA path elsewhere --
+                                 # kernels.tuning.KernelPolicy)
     attn_tq: Optional[int] = None  # Pallas query-tile rows override
                                  # (multiple of nr); None = the policy's
                                  # tuning table picks per launch
-    decode_impl: str = "jnp"     # serving decode tick: auto | jnp | pallas
+    decode_impl: str = "auto"    # serving decode tick: auto | jnp | pallas
                                  # | pallas_interpret (fused single-launch
                                  # hierarchical-KV attend + ancestor update)
     cache_dtype: str = "fp32"    # paged KV-page storage: fp32 | int8
@@ -138,23 +139,20 @@ def shard_if_divisible(size: int) -> Optional[str]:
 
 def logical(x: jnp.ndarray, *axes: Optional[str]) -> jnp.ndarray:
     """Activation sharding constraint; no-op outside a mesh context."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty:
-            return x
-        names = set(mesh.axis_names)
-        clean = []
-        for a in axes:
-            if a is None:
-                clean.append(None)
-            elif isinstance(a, str):
-                clean.append(a if a in names else None)
-            else:
-                sub = tuple(s for s in a if s in names)
-                clean.append(sub if sub else None)
-        return jax.lax.with_sharding_constraint(x, P(*clean))
-    except Exception:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
+    names = set(mesh.axis_names)
+    clean = []
+    for a in axes:
+        if a is None:
+            clean.append(None)
+        elif isinstance(a, str):
+            clean.append(a if a in names else None)
+        else:
+            sub = tuple(s for s in a if s in names)
+            clean.append(sub if sub else None)
+    return jax.lax.with_sharding_constraint(x, P(*clean))
 
 
 # ---------------------------------------------------------------------------

@@ -155,12 +155,12 @@ class JsonlEmitter:
     def __init__(self, path: str, period_s: float = 10.0):
         self.path = path
         self.period_s = float(period_s)
-        self._last = 0.0
+        self._last = None        # never emitted: the first call emits
         self.emitted = 0
 
     def maybe_emit(self) -> bool:
         now = time.monotonic()
-        if now - self._last < self.period_s:
+        if self._last is not None and now - self._last < self.period_s:
             return False
         self._last = now
         self.emit()

@@ -18,7 +18,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(fn: Callable, stage_params: Any, x: jnp.ndarray, *,
@@ -31,9 +30,9 @@ def pipeline_apply(fn: Callable, stage_params: Any, x: jnp.ndarray, *,
 
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(pspec, P(axis)), out_specs=P(axis),
-             check_rep=False)
+             check_vma=False)
     def run(params, xs):
         # params leaves: (1, ...) local stage slice; xs: (M/S, Bm, ...)
         # We want every stage to see ALL microbatches in sequence, so we

@@ -20,25 +20,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def axis_type_kwargs(n: int) -> dict:
-    """Version-compat mesh kwargs: jax >= 0.5 wants explicit
-    ``axis_types=(AxisType.Auto,) * n``; 0.4.x predates the kwarg
-    entirely (Auto is the only behaviour).  Single source of truth for
-    the AxisType probe -- also used by ``launch/mesh.py``."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n} if at is not None else {}
-
-
-def abstract_mesh(shape, axes):
-    """Version-compat ``jax.sharding.AbstractMesh`` constructor: jax >=
-    0.5 takes ``(shape, axes, axis_types=...)``; 0.4.x takes name/size
-    pairs."""
-    kw = axis_type_kwargs(len(axes))
-    if kw:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes), **kw)
-    return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
-
-
 def dp_axes(mesh: Mesh):
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 

@@ -58,10 +58,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover - newer jax moved it to the top level
-    from jax import shard_map
 
 from repro import obs
 from repro.core import hierarchy as hc
@@ -118,12 +114,7 @@ def _local_region():
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """Version-compat shard_map (check_rep was renamed check_vma)."""
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:  # pragma: no cover - newer jax
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
 
 

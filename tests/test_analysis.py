@@ -12,7 +12,7 @@ import pytest
 from repro.analysis import check, checker, vmem
 from repro.analysis.contracts import (LaunchContract, Operand, capture,
                                       recent)
-from repro.kernels import h1d_block, h1d_block_bwd
+from repro.kernels import h1d_block, h1d_block_bwd, tuning
 from repro.kernels.tuning import KernelPolicy, set_policy
 
 F32 = "float32"
@@ -393,7 +393,7 @@ def test_tune_cache_malformed_env_warns_and_defaults(monkeypatch,
         monkeypatch.setattr(os, "environ", {"REPRO_TUNE_CACHE": bad})
         with pytest.warns(RuntimeWarning, match="REPRO_TUNE_CACHE"):
             p = KernelPolicy()
-        assert p.cache_dir == os.path.expanduser("~/.cache/repro_tune")
+        assert p.cache_dir == tuning.DEFAULT_CACHE_DIR
     # a usable path passes through silently
     monkeypatch.setenv("REPRO_TUNE_CACHE", str(tmp_path))
     import warnings

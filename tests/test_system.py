@@ -70,10 +70,11 @@ def test_parse_collectives_on_synthetic_hlo():
 
 
 def test_cache_shardings_heuristics():
-    from repro.parallel import abstract_mesh, cache_shardings
-    # spec-only: abstract mesh needs no real devices (version-compat
-    # constructor: the AbstractMesh signature changed across jax 0.4/0.5)
-    mesh = abstract_mesh((2, 2), ("data", "model"))
+    from repro.parallel import cache_shardings
+    # spec-only: an abstract mesh needs no real devices
+    mesh = jax.sharding.AbstractMesh(
+        (2, 2), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
     big = jnp.zeros((8, 64, 4))       # batch-major, divisible by dp*tp
     small = jnp.zeros((3, 64, 4))     # not divisible -> replicated
     sh = cache_shardings(mesh, {"a": big, "b": small}, batch=8, kv_heads=1,
