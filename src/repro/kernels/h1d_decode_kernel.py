@@ -86,16 +86,16 @@ def _pairs(a):
     """(..., L, D) level array -> (..., L/2, 2, D) sibling-pair view.
     Mosaic refuses a 2-row block over the (L, D) dims (the second-minor
     block dim must be a multiple of 8 or the whole dim); over the pair
-    view the block's trailing (2, D) is the whole of those dims.  For
-    f32 and bf16 levels XLA compiles the reshape to a bitcast: both
-    layouts keep each row at the same 128-lane stride."""
+    view the block's trailing (2, D) is the whole of those dims.  At
+    head_dim 128 XLA compiles the reshape of an f32 or bf16 level to a
+    bitcast (``tests/test_tpu_compile.py`` checks it); an int8 level's
+    does not fold, so the int8 update moves whole pages instead."""
     return a.reshape(*a.shape[:-2], a.shape[-2] // 2, 2, a.shape[-1])
 
 
 def _rows3(x):
-    """(R, D) per-row vectors (new rows, or a page's nr int8 scales) ->
-    (R, 1, D), so a (1, 1, D) row block's trailing dims are the array's
-    own."""
+    """(R, D) new rows -> (R, 1, D), so a (1, 1, D) row block's
+    trailing dims are the array's own."""
     return x[:, None, :]
 
 
@@ -111,14 +111,14 @@ def _attend_kernel(t_ref, q_ref, *refs, nr: int, nbands: int, scale: float,
 
     ``quant`` (per-band bools, empty = all fp) marks int8 bands: their
     K/V blocks arrive as int8 pages and are dequantized in VMEM with the
-    per-row scale blocks appended after the V refs (k-scales for the
-    quantized bands in band order, then v-scales)."""
+    row's gathered ``(nq, nr, 1)`` k-scale and v-scale blocks, appended
+    after the V refs (quantized bands in band order)."""
     nq = sum(quant)
     k_refs = refs[:nbands]
     v_refs = refs[nbands:2 * nbands]
-    ksc_refs = refs[2 * nbands:2 * nbands + nq]
-    vsc_refs = refs[2 * nbands + nq:2 * nbands + 2 * nq]
-    o_ref = refs[2 * nbands + 2 * nq]
+    if nq:
+        ksc_ref, vsc_ref = refs[2 * nbands:2 * nbands + 2]
+    o_ref = refs[2 * nbands + (2 if nq else 0)]
     r = pl.program_id(0)
     t = t_ref[r]
     f32 = jnp.float32
@@ -133,8 +133,8 @@ def _attend_kernel(t_ref, q_ref, *refs, nr: int, nbands: int, scale: float,
         kb = k_refs[band][0].astype(f32)                 # (nr, D)
         vb = v_refs[band][0].astype(f32)                 # (nr, Dv)
         if quant and quant[band]:
-            kb = kb * ksc_refs[si][0].reshape(nr, 1)     # (1, nr) row
-            vb = vb * vsc_refs[si][0].reshape(nr, 1)
+            kb = kb * ksc_ref[0, si]                    # (nr, 1) column
+            vb = vb * vsc_ref[0, si]
             si += 1
         s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=f32)   # (G, nr)
@@ -513,13 +513,15 @@ def decode_attend_paged_quant(pool, q: jnp.ndarray, t: jnp.ndarray,
 
     ``pool`` is a ``core.h1d_decode.QuantPagedH1DCache``: int8 pages for
     any subset of levels, with per-row f32 scales ``(NP_l, nr)``.  The
-    scales ride the SAME scalar-prefetched ``bidx`` indirection as the
-    pages -- one extra ``(1, 1, nr)`` scale block per quantized band, whose
-    index map reads the identical table column -- and the dequantize
-    (one multiply per gathered row) happens in VMEM right before the
-    QK^T dot.  Still one launch on the (R,) grid; fp32 levels of a
-    mixed-precision pool skip the scale operands entirely (which levels
-    are quantized is static in the array dtypes)."""
+    scale rows of each row's quantized bands are gathered by XLA through
+    the same ``bidx`` table into two small ``(R, nq, nr, 1)`` operands:
+    the TPU keeps ``(NP, nr)`` arrays lane-transposed, so a kernel
+    operand over the scale pool itself would cost a relayout copy of
+    all of it per launch.  The dequantize (one multiply per gathered
+    row) happens in VMEM right before the QK^T dot.  Still one launch on
+    the (R,) grid; fp32 levels of a mixed-precision pool have no scale
+    operands (which levels are quantized is static in the array
+    dtypes)."""
     hc = _hc()
     R, G, D = q.shape
     Dv = pool.v.shape[-1]
@@ -535,33 +537,30 @@ def decode_attend_paged_quant(pool, q: jnp.ndarray, t: jnp.ndarray,
     def band_map(band):
         return lambda r, tref, bref: (bref[r, band], 0, 0)
 
-    def band_map_sc(band):
-        return lambda r, tref, bref: (bref[r, band], 0, 0)
-
     maps = [band_map(b) for b in range(nbands)]
     k_arrs = [pool.k, pool.k] + list(pool.ck)
     v_arrs = [pool.v, pool.v] + list(pool.cv)
     ksc_all = [pool.ksc, pool.ksc] + list(pool.cksc)
     vsc_all = [pool.vsc, pool.vsc] + list(pool.cvsc)
-    sc_arrs, sc_specs = [], []
-    for scs in (ksc_all, vsc_all):         # k-scales first, then v-scales
-        for b in range(nbands):
-            if quant[b]:
-                sc_arrs.append(_rows3(scs[b]))
-                sc_specs.append(pl.BlockSpec((1, 1, nr), band_map_sc(b)))
+    qbands = [b for b in range(nbands) if quant[b]]
+    # k-scales, then v-scales: (R, nq, nr, 1) per-row gathers
+    sc_arrs = [jnp.stack([scs[b][bidx[:, b]] for b in qbands],
+                         axis=1)[..., None]
+               for scs in (ksc_all, vsc_all)] if qbands else []
+    row4 = lambda r, tref, bref: (r, 0, 0, 0)
 
     in_specs = [pl.BlockSpec((1, G, D), lambda r, tref, bref: (r, 0, 0))]
     in_specs += [pl.BlockSpec((1, nr, D), mp) for mp in maps]
     in_specs += [pl.BlockSpec((1, nr, Dv), mp) for mp in maps]
-    in_specs += sc_specs
+    in_specs += [pl.BlockSpec((1, len(qbands), nr, 1), row4)
+                 for _ in sc_arrs]
 
     kernel = functools.partial(_attend_paged_kernel, nr=nr, nbands=nbands,
                                scale=float(scale), neg_inf=hc.NEG_INF,
                                quant=quant)
     bidx_hi = np.array([a.shape[0] - 1 for a in k_arrs], dtype=np.int32)
     bn = _band_names(nbands)
-    sc_names = [f"{a}sc_{bn[b]}" for a in "kv" for b in range(nbands)
-                if quant[b]]
+    sc_names = ["ksc", "vsc"] if qbands else []
     out = launch(
         kernel, family="decode_attend_paged_quant", grid=(R,),
         in_specs=in_specs,
@@ -650,48 +649,50 @@ def update_cache_paged(pool, k_new: jnp.ndarray, v_new: jnp.ndarray,
 def _update_paged_quant_kernel(t_ref, utab_ref, knew_ref, vnew_ref, *refs,
                                nlev: int, nr: int, quant, qmax: float,
                                recip: float, eps: float):
-    """Quantized variant of :func:`_update_kernel`: at each int8 level
-    the 2-row sibling pair is dequantized with its per-row scales, the
-    new row substituted, and the pair REquantized in place (fresh absmax
-    scales -- the same rounding as ``core.quantization.quantize_int8``,
-    inlined so it runs on the VMEM-resident pair).  The ancestor carry
-    is the PRE-quantization f32 pair mean/sum, so quantization error
-    does not compound up the hierarchy within a tick."""
+    """Quantized variant of :func:`_update_kernel` over whole pages: at
+    each level the 2-row sibling pair is picked out of its ``(nr, D)``
+    page, dequantized with its two per-row scales (int8 levels), the new
+    row substituted, and the pair REquantized (fresh absmax scales --
+    the same rounding as ``core.quantization.quantize_int8``, inlined so
+    it runs on the VMEM-resident pair); the page goes back with its
+    other rows unchanged.  The ancestor carry is the PRE-quantization
+    f32 pair mean/sum, so quantization error does not compound up the
+    hierarchy within a tick.  ``osc``/``nsc`` hold each int8 level's
+    old/new pair scales as a ``(4, 1)`` column: k0, k1, v0, v1."""
     nq = sum(quant)
     in_data = refs[:2 * nlev]
-    in_sc = refs[2 * nlev:2 * nlev + 2 * nq]
-    out_data = refs[2 * nlev + 2 * nq:4 * nlev + 2 * nq]
-    out_sc = refs[4 * nlev + 2 * nq:]
+    out_data = refs[2 * nlev + (1 if nq else 0):4 * nlev + (1 if nq else 0)]
+    if nq:
+        osc_ref, nsc_ref = refs[2 * nlev], refs[-1]
     r = pl.program_id(0)
     t = t_ref[r]
     f32 = jnp.float32
     sel_row = jax.lax.broadcasted_iota(jnp.int32, (2, 1), 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, nr), 1)
+    page_row = jax.lax.broadcasted_iota(jnp.int32, (nr, 1), 0)
 
     new_k = knew_ref[0].astype(f32)                      # (1, D)
     new_v = vnew_ref[0].astype(f32)                      # (1, Dv)
     si = 0
     for l in range(nlev):
         sel = sel_row == ((t >> l) & 1)
-        kd = in_data[2 * l][0, 0].astype(f32)            # (2, D)
-        vd = in_data[2 * l + 1][0, 0].astype(f32)
+        p0 = 2 * ((t >> (l + 1)) & (nr // 2 - 1))       # pair's first row
+        at0, at1 = page_row == p0, page_row == p0 + 1
+
+        def pair(page):             # (nr, D) -> the pair's (2, D)
+            return jnp.concatenate(
+                [jnp.sum(jnp.where(at, page, 0.0), axis=0, keepdims=True)
+                 for at in (at0, at1)], axis=0)
+
+        def put(page, p2):          # the page with the pair's rows = p2
+            return jnp.where(at0, p2[0:1], jnp.where(at1, p2[1:2], page))
+
+        kpage = in_data[2 * l][0].astype(f32)            # (nr, D)
+        vpage = in_data[2 * l + 1][0].astype(f32)
+        kd, vd = pair(kpage), pair(vpage)
         if quant[l]:
-            # the page's (1, nr) scale row; the pair is lanes 2p, 2p+1
-            p2 = 2 * ((t >> (l + 1)) & (nr // 2 - 1))
-            at0, at1 = lane == p2, lane == p2 + 1
-
-            def pair_col(row):          # (1, nr) -> the pair's (2, 1)
-                s0 = jnp.sum(jnp.where(at0, row, 0.0), axis=1, keepdims=True)
-                s1 = jnp.sum(jnp.where(at1, row, 0.0), axis=1, keepdims=True)
-                return jnp.where(sel_row == 0, s0, s1)
-
-            def put_pair(row, col):     # write the pair's (2, 1) back
-                return jnp.where(at0, col[0:1], jnp.where(at1, col[1:2], row))
-
-            ksc_row = in_sc[2 * si][0]
-            vsc_row = in_sc[2 * si + 1][0]
-            kd = kd * pair_col(ksc_row)
-            vd = vd * pair_col(vsc_row)
+            osc = osc_ref[0, si]                         # (4, 1)
+            kd = kd * osc[0:2]
+            vd = vd * osc[2:4]
         pk = jnp.where(sel, new_k, kd)
         pv = jnp.where(sel, new_v, vd)
         if quant[l]:
@@ -699,16 +700,16 @@ def _update_paged_quant_kernel(t_ref, utab_ref, knew_ref, vnew_ref, *refs,
                               eps) * recip
             vsc = jnp.maximum(jnp.max(jnp.abs(pv), axis=1, keepdims=True),
                               eps) * recip
-            out_data[2 * l][0, 0] = jnp.clip(jnp.round(pk / ksc),
-                                             -qmax, qmax).astype(jnp.int8)
-            out_data[2 * l + 1][0, 0] = jnp.clip(jnp.round(pv / vsc),
-                                                 -qmax, qmax).astype(jnp.int8)
-            out_sc[2 * si][0] = put_pair(ksc_row, ksc)
-            out_sc[2 * si + 1][0] = put_pair(vsc_row, vsc)
+            qk = jnp.clip(jnp.round(pk / ksc), -qmax, qmax)
+            qv = jnp.clip(jnp.round(pv / vsc), -qmax, qmax)
+            out_data[2 * l][0] = put(kpage, qk).astype(jnp.int8)
+            out_data[2 * l + 1][0] = put(vpage, qv).astype(jnp.int8)
+            nsc_ref[0, si] = jnp.concatenate([ksc, vsc], axis=0)
             si += 1
         else:
-            out_data[2 * l][0, 0] = pk.astype(out_data[2 * l].dtype)
-            out_data[2 * l + 1][0, 0] = pv.astype(out_data[2 * l + 1].dtype)
+            out_data[2 * l][0] = put(kpage, pk).astype(out_data[2 * l].dtype)
+            out_data[2 * l + 1][0] = put(vpage, pv).astype(
+                out_data[2 * l + 1].dtype)
         if l + 1 < nlev:
             new_k = pk.mean(axis=0, keepdims=True)       # Eq. 25/26
             new_v = pv.sum(axis=0, keepdims=True)        # Eq. 27
@@ -720,13 +721,16 @@ def update_cache_paged_quant(pool, k_new: jnp.ndarray, v_new: jnp.ndarray,
     """Fused batched append into a QUANTIZED paged pool
     (``core.h1d_decode.QuantPagedH1DCache``).
 
-    Same single-launch in-place scatter as :func:`update_cache_paged`;
-    each quantized level additionally carries its page's ``(1, 1, nr)``
-    per-row scale row, whose index map reads the SAME ``utab`` column as
-    its data block, aliased input->output so both the int8 pair and its
-    two scales (lanes ``2p, 2p+1`` of the row; the others pass through
-    unchanged) rewrite in place.  fp32 levels of a mixed pool pass their
-    scale arrays through untouched (never kernel operands)."""
+    Same single-launch in-place scatter as :func:`update_cache_paged`,
+    but each level moves its whole ``(1, nr, D)`` page, aliased
+    input->output: an int8 pair view ``(..., nr/2, 2, D)`` does not fold
+    into the TPU's int8 tiling, and would cost a relayout copy of the
+    pool per launch.  The scales stay out of the kernel for the same
+    reason (the ``(NP, nr)`` scale arrays are lane-transposed on TPU):
+    XLA gathers each int8 level's two old pair scales into one small
+    ``(R, nq, 4, 1)`` operand and scatters the kernel's fresh ones back
+    into the (donated) scale arrays.  fp32 levels of a mixed pool keep
+    their scale arrays untouched."""
     qz = _qz()
     R, D = k_new.shape
     Dv = v_new.shape[-1]
@@ -734,83 +738,71 @@ def update_cache_paged_quant(pool, k_new: jnp.ndarray, v_new: jnp.ndarray,
     nlev = 1 + len(pool.ck)
     assert utab.shape == (R, nlev), (utab.shape, R, nlev)
     quant = tuple(bool(a.dtype == jnp.int8) for a in (pool.k, *pool.ck))
+    nq = sum(quant)
+    t = t.astype(jnp.int32)
+    utab = utab.astype(jnp.int32)
 
-    data_arrs, data_in, data_out, data_shape = [], [], [], []
-    sc_arrs, sc_in, sc_out, sc_shape = [], [], [], []
-    lvls = ([(pool.k, pool.v, pool.ksc, pool.vsc)]
-            + list(zip(pool.ck, pool.cv, pool.cksc, pool.cvsc)))
-    for l, (ka, va, ksa, vsa) in enumerate(lvls):
+    lvls = list(zip([pool.k] + list(pool.ck), [pool.v] + list(pool.cv)))
+    ksc = [pool.ksc] + list(pool.cksc)
+    vsc = [pool.vsc] + list(pool.cvsc)
+    qlev = [l for l in range(nlev) if quant[l]]
+    # each int8 level's pair-scale columns (R, 2) and their page rows
+    cols = {l: (2 * ((t >> (l + 1)) & (nr // 2 - 1)))[:, None]
+            + jnp.arange(2) for l in qlev}
+    pages = {l: utab[:, l][:, None] for l in qlev}
 
-        def pair_map(r, tref, uref, l=l):
-            return (uref[r, l], (tref[r] >> (l + 1)) & (nr // 2 - 1), 0, 0)
+    data, data_specs = [], []
+    for l, (ka, va) in enumerate(lvls):
 
-        # a page's whole (1, nr) scale row: a (1, 2) pair block over
-        # (NP, nr) is refused, and pages are private to their writer
-        def page_map_sc(r, tref, uref, l=l):
+        def page_map(r, tref, uref, l=l):
             return (uref[r, l], 0, 0)
 
         for a, d_ in ((ka, D), (va, Dv)):
-            data_arrs.append(_pairs(a))
-            data_in.append(pl.BlockSpec((1, 1, 2, d_), pair_map))
-            data_out.append(pl.BlockSpec((1, 1, 2, d_), pair_map))
-            data_shape.append(jax.ShapeDtypeStruct(data_arrs[-1].shape,
-                                                   a.dtype))
-        if quant[l]:
-            for a in (ksa, vsa):
-                sc_arrs.append(_rows3(a))
-                sc_in.append(pl.BlockSpec((1, 1, nr), page_map_sc))
-                sc_out.append(pl.BlockSpec((1, 1, nr), page_map_sc))
-                sc_shape.append(jax.ShapeDtypeStruct(sc_arrs[-1].shape,
-                                                     a.dtype))
+            data.append(a)
+            data_specs.append(pl.BlockSpec((1, nr, d_), page_map))
+    sc_ops, sc_specs, sc_shape = [], [], []
+    if nq:
+        osc = jnp.stack([jnp.concatenate(
+            [ksc[l][pages[l], cols[l]], vsc[l][pages[l], cols[l]]], axis=1)
+            for l in qlev], axis=1)[..., None]           # (R, nq, 4, 1)
+        sc_ops = [osc]
+        sc_specs = [pl.BlockSpec((1, nq, 4, 1),
+                                 lambda r, tref, uref: (r, 0, 0, 0))]
+        sc_shape = [jax.ShapeDtypeStruct(osc.shape, jnp.float32)]
 
     row_map = lambda r, tref, uref: (r, 0, 0)
-    # (k_new, v_new, *data_arrs, *sc_arrs): every pool operand (payload
-    # AND scale blocks) aliases its mirror output; operand-indexed.
-    nio = 2 * nlev + 2 * sum(quant)
-    aliases = {2 + i: i for i in range(nio)}
+    # (k_new, v_new, *data, osc): every pool page operand aliases its
+    # mirror output; operand-indexed.  nsc is a fresh per-row output.
+    aliases = {2 + i: i for i in range(2 * nlev)}
     kernel = functools.partial(_update_paged_quant_kernel, nlev=nlev, nr=nr,
                                quant=quant, qmax=qz.QMAX,
                                recip=qz.RECIP_QMAX, eps=qz.EPS)
-    utab_hi = np.array([ka.shape[0] - 1 for ka, _, _, _ in lvls],
-                       dtype=np.int32)
+    utab_hi = np.array([ka.shape[0] - 1 for ka, _ in lvls], dtype=np.int32)
     lvl_names = [f"{a}_l{l}" for l in range(nlev) for a in ("k", "v")]
-    sc_names = [f"{a}sc_l{l}" for l in range(nlev) for a in ("k", "v")
-                if quant[l]]
     outs = launch(
         kernel, family="decode_update_paged_quant", grid=(R,),
         in_specs=[pl.BlockSpec((1, 1, D), row_map),
-                  pl.BlockSpec((1, 1, Dv), row_map)] + data_in + sc_in,
-        out_specs=tuple(data_out + sc_out),
-        out_shape=tuple(data_shape + sc_shape),
-        operands=[_rows3(k_new), _rows3(v_new), *data_arrs, *sc_arrs],
-        scalars=(t.astype(jnp.int32), utab.astype(jnp.int32)),
+                  pl.BlockSpec((1, 1, Dv), row_map)] + data_specs + sc_specs,
+        out_specs=tuple(data_specs + sc_specs),
+        out_shape=tuple([jax.ShapeDtypeStruct(a.shape, a.dtype)
+                         for a in data] + sc_shape),
+        operands=[_rows3(k_new), _rows3(v_new), *data, *sc_ops],
+        scalars=(t, utab),
         scalar_bounds=((0, _T_MAX), (0, utab_hi)),
         scalar_names=("t", "utab"),
-        in_names=["k_new", "v_new"] + lvl_names + sc_names,
-        out_names=lvl_names + sc_names, aliases=aliases,
+        in_names=["k_new", "v_new"] + lvl_names + (["osc"] if nq else []),
+        out_names=lvl_names + (["nsc"] if nq else []), aliases=aliases,
         interpret=interpret,
         meta=dict(nr=nr, nlev=nlev, quant=quant))
-    data = [o.reshape(*o.shape[:-3], -1, o.shape[-1])
-            for o in outs[:2 * nlev]]
-    scs = [o[:, 0, :] for o in outs[2 * nlev:]]
-    ksc_out, vsc_out = [], []
-    all_ks = [pool.ksc] + list(pool.cksc)
-    all_vs = [pool.vsc] + list(pool.cvsc)
-    si = 0
-    for l in range(nlev):
-        if quant[l]:
-            ksc_out.append(scs[2 * si])
-            vsc_out.append(scs[2 * si + 1])
-            si += 1
-        else:
-            ksc_out.append(all_ks[l])
-            vsc_out.append(all_vs[l])
+    for si, l in enumerate(qlev):
+        nsc = outs[2 * nlev][:, si, :, 0]                # (R, 4)
+        ksc[l] = ksc[l].at[pages[l], cols[l]].set(nsc[:, 0:2])
+        vsc[l] = vsc[l].at[pages[l], cols[l]].set(nsc[:, 2:4])
     return type(pool)(
-        k=data[0], v=data[1],
-        ck=tuple(data[2 + 2 * i] for i in range(nlev - 1)),
-        cv=tuple(data[3 + 2 * i] for i in range(nlev - 1)),
-        ksc=ksc_out[0], vsc=vsc_out[0],
-        cksc=tuple(ksc_out[1:]), cvsc=tuple(vsc_out[1:]))
+        k=outs[0], v=outs[1],
+        ck=tuple(outs[2 + 2 * i] for i in range(nlev - 1)),
+        cv=tuple(outs[3 + 2 * i] for i in range(nlev - 1)),
+        ksc=ksc[0], vsc=vsc[0], cksc=tuple(ksc[1:]), cvsc=tuple(vsc[1:]))
 
 
 # ---------------------------------------------------------------------------

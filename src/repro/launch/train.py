@@ -22,9 +22,32 @@ from repro.data import ZipfLM, HierarchicalLM, Prefetcher
 from repro.launch import compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import set_mesh_axes
-from repro.parallel import param_shardings
+from repro.parallel import param_shardings, sp_scope
 from repro.train import (TrainConfig, TrainState, init_state,
                          make_train_step, Watchdog, checkpoint as ckpt)
+
+
+def build(cfg, tc: TrainConfig, mesh, sp: bool = False):
+    """The sharded initial state (seeded by ``tc.seed``) and the train
+    step, as :func:`main` runs them; call inside ``jax.set_mesh(mesh)``.
+    With ``sp`` the step traces under ``sp_scope(mesh, 'data')``, so
+    every kernel-path attention call shards its sequence axis over
+    'data'."""
+    set_mesh_axes(mesh.shape.get("model"))
+    state, specs = init_state(jax.random.PRNGKey(tc.seed), cfg, tc)
+    psh = param_shardings(mesh, specs)
+    state = TrainState(
+        state.step,
+        jax.tree.map(jax.device_put, state.params, psh),
+        state.opt_state, state.ef_state)
+    step = make_train_step(cfg, tc)
+    if sp:
+        inner = step
+
+        def step(state, batch):
+            with sp_scope(mesh, "data"):
+                return inner(state, batch)
+    return state, step
 
 
 def main(argv=None):
@@ -71,7 +94,6 @@ def main(argv=None):
     dshape = tuple(int(x) for x in args.mesh.split("x"))
     mesh = make_mesh(dshape, ("data", "model")[:len(dshape)] if
                      len(dshape) == 2 else ("data",))
-    set_mesh_axes(mesh.shape.get("model"))
 
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     tc = TrainConfig(peak_lr=args.lr, total_steps=args.steps,
@@ -85,30 +107,12 @@ def main(argv=None):
     data = src_cls(vocab_size=cfg.vocab_size, seq_len=args.seq,
                    batch_per_host=args.batch, seed=args.seed)
 
-    key = jax.random.PRNGKey(args.seed)
     with jax.set_mesh(mesh):
-        state, specs = init_state(key, cfg, tc)
-        psh = param_shardings(mesh, specs)
-        state = TrainState(
-            state.step,
-            jax.tree.map(jax.device_put, state.params, psh),
-            state.opt_state, state.ef_state)
-
+        state, raw_step = build(cfg, tc, mesh, sp=args.sp)
         start = ckpt.latest_step(tc.ckpt_dir) if args.ckpt_every else None
         if start is not None:
             state = ckpt.restore(tc.ckpt_dir, start, state)
             print(f"[restart] resumed from step {start}")
-
-        raw_step = make_train_step(cfg, tc)
-        if args.sp:
-            # enter the SP scope while TRACING, so every kernel-path
-            # attention call shards its sequence axis over 'data'
-            from repro.parallel import sp_scope
-
-            def sp_step(state, batch, _inner=raw_step):
-                with sp_scope(mesh, "data"):
-                    return _inner(state, batch)
-            raw_step = sp_step
         step_fn = jax.jit(raw_step, donate_argnums=(0,))
         saver = ckpt.AsyncCheckpointer(tc.ckpt_dir)
         wd = Watchdog()

@@ -43,7 +43,7 @@ def test_one_chip_phases_at_smoke_size(chip_smoke, capsys):
     plan = chip_smoke.Plan(
         serve_cfg=_cfg("llama3.2-1b"), train_cfg=_cfg("h1d-lm-144m"),
         impl=IMPL, slots=2, max_len=256, requests=3, prompt_range=(40, 120),
-        parity_len=96, seq=128, batch=2, steps=2)
+        parity_prompts=(96, 60), seq=128, batch=2, steps=2)
     try:
         chip_smoke.one_chip(plan)
     finally:

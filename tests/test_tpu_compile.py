@@ -14,6 +14,7 @@ runs this file is the only one that touches it.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -101,9 +102,7 @@ def _decode_step(cache_kind):
     return step
 
 
-@pytest.mark.parametrize("cache_kind", ["dense", "paged", "int8_paged"])
-@pytest.mark.parametrize("d,dtype", [(64, jnp.bfloat16), (128, jnp.float32)])
-def test_decode_attend_update_compiles(one_chip, cache_kind, d, dtype):
+def _decode_args(one_chip, cache_kind, d, dtype):
     R, Lmax = 32, 4096                 # 4 slots x 8 kv heads
     levels = hc.num_levels(Lmax, NR) - 1
     S = lambda shape, dt=dtype: _sds(one_chip, shape, dt)  # noqa: E731
@@ -123,4 +122,45 @@ def test_decode_attend_update_compiles(one_chip, cache_kind, d, dtype):
             S((R,), jnp.int32)]
     if cache_kind != "dense":
         args += [S((R, 2 + levels), jnp.int32), S((R, 1 + levels), jnp.int32)]
-    _compiled_text(_decode_step(cache_kind), *args)
+    return args
+
+
+@pytest.mark.parametrize("cache_kind", ["dense", "paged", "int8_paged"])
+@pytest.mark.parametrize("d,dtype", [(64, jnp.bfloat16), (128, jnp.float32)])
+def test_decode_attend_update_compiles(one_chip, cache_kind, d, dtype):
+    _compiled_text(_decode_step(cache_kind),
+                   *_decode_args(one_chip, cache_kind, d, dtype))
+
+
+# head_dim 128 only: the TPU lays out an array whose minor dim is 64 with
+# that dim off the lanes, so every kernel over a head_dim-64 cache pays
+# a relayout of it (PERF.md, open questions)
+@pytest.mark.parametrize("cache_kind", ["dense", "paged", "int8_paged"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_step_updates_cache_in_place(one_chip, cache_kind, dtype):
+    """With the cache donated, a decode tick moves O(rows) bytes of the
+    K/V levels: no copy or transpose of any level-sized array, and every
+    cache leaf aliased input -> output.  (The int8 pool's (NP, nr) scale
+    arrays, 1/32 of its bytes at head_dim 128, are not held to this: the
+    TPU keeps them lane-transposed, and the attend's row gather
+    relayouts them -- PERF.md, open questions.)"""
+    args = _decode_args(one_chip, cache_kind, 128, dtype)
+    leaves = jax.tree.leaves(args[0])
+    text = jax.jit(_decode_step(cache_kind), donate_argnums=0).lower(
+        *args).compile().as_text()
+    assert "tpu_custom_call" in text
+    levels = [a for a in leaves if a.ndim == 3]
+    big = {tuple(a.shape) for a in levels}
+    big |= {(*a.shape[:-2], a.shape[-2] // 2, 2, a.shape[-1])
+            for a in levels}
+    moved = [m.group(0)[:120] for m in re.finditer(
+        r"= \w+\[([\d,]*)\]\S* (?:copy|transpose)\(.*", text)
+        if tuple(int(x) for x in m.group(1).split(",") if x) in big]
+    assert not moved, moved
+    header = text.split("\n", 1)[0]
+    # "{output}: (parameter, {}, may-alias)"; both number the leaves in
+    # order, cache first
+    aliased = {(int(o), int(p)) for o, p in
+               re.findall(r"\{(\d+)\}: \((\d+), \{", header)}
+    want = {(i, i) for i in range(len(leaves))}
+    assert want <= aliased, sorted(want - aliased)
