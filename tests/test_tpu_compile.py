@@ -154,7 +154,8 @@ def test_decode_step_updates_cache_in_place(one_chip, cache_kind, dtype):
     big |= {(*a.shape[:-2], a.shape[-2] // 2, 2, a.shape[-1])
             for a in levels}
     moved = [m.group(0)[:120] for m in re.finditer(
-        r"= \w+\[([\d,]*)\]\S* (?:copy|transpose)\(.*", text)
+        r"= \(?\w+\[([\d,]*)\]\S* (?:copy|copy-start|transpose)\(.*",
+        text)
         if tuple(int(x) for x in m.group(1).split(",") if x) in big]
     assert not moved, moved
     header = text.split("\n", 1)[0]
