@@ -1,0 +1,9 @@
+"""Training: share of the traced window in which no operation ran on the device,
+in %: 1 - (union of device-op intervals) / window."""
+
+
+def read(r):
+    t = r["trace"]
+    if not t["window_s"] or not t["busy_s"]:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
