@@ -47,10 +47,11 @@ def main():
                     help="paged page storage dtype (int8: per-row "
                          "scales, ~4x pages at fixed HBM)")
     ap.add_argument("--telemetry", action="store_true",
-                    help="enable repro.obs metrics + spans and print a "
-                         "summary (implied by --trace-out / --prom-out)")
-    ap.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="write a Chrome trace-event JSON (load it at "
+                    help="enable repro.obs metrics and print a summary "
+                         "(implied by --prom-out)")
+    ap.add_argument("--trace-out", default=None, metavar="DIR",
+                    help="record a jax.profiler trace of serving into DIR "
+                         "(load its perfetto_trace.json.gz at "
                          "ui.perfetto.dev)")
     ap.add_argument("--prom-out", default=None, metavar="PATH",
                     help="write a Prometheus text exposition of the "
@@ -58,7 +59,7 @@ def main():
     args = ap.parse_args()
 
     from repro import obs
-    if args.telemetry or args.trace_out or args.prom_out:
+    if args.telemetry or args.prom_out:
         obs.enable()
 
     cfg = get_smoke_config(args.arch)
@@ -84,9 +85,10 @@ def main():
 
     t0 = time.perf_counter()
     ticks = 0
-    while eng.queue or eng.active.any():
-        eng.step()
-        ticks += 1
+    with obs.tracing.profile(args.trace_out):
+        while eng.queue or eng.active.any():
+            eng.step()
+            ticks += 1
     dt = time.perf_counter() - t0
     total = sum(len(r.out_tokens) for r in reqs)
     print(f"served {len(reqs)} requests / {total} tokens in {dt:.2f}s "
@@ -108,14 +110,12 @@ def main():
                   if k.startswith("kernel.hbm_"))
         print(f"telemetry: ticks={c.get('serve.ticks', 0)} "
               f"launches={sum(v for k, v in c.items() if k.startswith('kernel.launches'))} "
-              f"analytic_hbm_bytes={hbm} "
-              f"trace_events={snap['trace']['events']}")
-        if args.trace_out:
-            obs.export.write_trace(args.trace_out)
-            print(f"telemetry: wrote Chrome trace -> {args.trace_out}")
+              f"analytic_hbm_bytes={hbm}")
         if args.prom_out:
             obs.export.write_prometheus(args.prom_out)
             print(f"telemetry: wrote Prometheus text -> {args.prom_out}")
+    if args.trace_out:
+        print(f"wrote a profiler trace -> {args.trace_out}")
 
 
 if __name__ == "__main__":

@@ -65,16 +65,17 @@ timeout 60 python examples/serve_batched.py --paged --cache-dtype int8 \
     --pool-pages 24 --requests 4 --slots 2 --new-tokens 4 > /dev/null
 echo "examples OK"
 
-echo "== telemetry smoke (trace + prometheus vs pinned schemas) =="
-# telemetry-enabled paged serve; pallas_interpret keeps the launch path
-# (and therefore kernel.launch analytic-traffic events) live on CPU.
-# The artifacts are validated by the SAME repro.obs.export validators
-# the unit tests pin, so CI and tests cannot drift apart.
+echo "== telemetry smoke (profiler trace + prometheus vs pinned schemas) =="
+# telemetry-enabled paged serve recorded into a jax.profiler trace
+# directory; pallas_interpret keeps the launch path (and therefore the
+# kernel.* analytic-traffic counters) live on CPU.  The artifacts are
+# validated by the SAME repro.obs.export validators the unit tests pin,
+# so CI and tests cannot drift apart.
 obs_dir="$(mktemp -d)"
 timeout 60 python examples/serve_batched.py --paged --pool-pages 24 \
     --decode-impl pallas_interpret --requests 4 --slots 2 \
-    --new-tokens 4 --telemetry --trace-out "$obs_dir/trace.json" \
+    --new-tokens 4 --telemetry --trace-out "$obs_dir/trace" \
     --prom-out "$obs_dir/metrics.prom" > /dev/null
 timeout 60 python scripts/check_telemetry.py \
-    --trace "$obs_dir/trace.json" --prom "$obs_dir/metrics.prom" \
+    --trace-dir "$obs_dir/trace" --prom "$obs_dir/metrics.prom" \
     --require-kernel-traffic

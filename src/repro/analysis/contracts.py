@@ -36,6 +36,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from jax.experimental import pallas as pl
@@ -165,6 +166,13 @@ def _operands(names, arrays, specs, kind: str) -> Tuple[Operand, ...]:
         for nm, a, sp in zip(names, arrays, specs))
 
 
+def _kernel_name(kernel) -> str:
+    """The kernel body's function name, through ``functools.partial``."""
+    while isinstance(kernel, functools.partial):
+        kernel = kernel.func
+    return getattr(kernel, "__name__", type(kernel).__name__)
+
+
 def launch(kernel, *, family: str, grid: Tuple[int, ...],
            in_specs: Sequence[pl.BlockSpec], out_specs, out_shape,
            operands: Sequence[Any], scalars: Sequence[Any] = (),
@@ -182,6 +190,11 @@ def launch(kernel, *, family: str, grid: Tuple[int, ...],
     ``(lo, hi)`` domain in ``scalar_bounds``.  ``aliases`` maps operand
     index -> output index; the translation to Pallas call-arg indices
     (which count the scalar args first) happens here, once.
+
+    The call is named after ``family`` and carries ``{"family",
+    "kernel"}`` as its kernel metadata, which the compiled program keeps
+    in the custom call's ``kernel_metadata`` attribute: a profiler trace
+    names each kernel event by it.
     """
     out_specs_t = _as_tuple(out_specs)
     out_shape_t = _as_tuple(out_shape)
@@ -220,6 +233,8 @@ def launch(kernel, *, family: str, grid: Tuple[int, ...],
     # scalar-prefetch args, so shift the operand index by len(scalars).
     ns = len(scalars)
     call_aliases = {ns + i: o for i, o in alias_items}
+    named = dict(name=family, metadata={"family": family,
+                                        "kernel": _kernel_name(kernel)})
     if ns:
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=ns, grid=tuple(grid),
@@ -227,9 +242,9 @@ def launch(kernel, *, family: str, grid: Tuple[int, ...],
         return pl.pallas_call(
             kernel, grid_spec=grid_spec, out_shape=out_shape,
             input_output_aliases=call_aliases, interpret=interpret,
-        )(*scalars, *operands)
+            **named)(*scalars, *operands)
     return pl.pallas_call(
         kernel, grid=tuple(grid), in_specs=list(in_specs),
         out_specs=out_specs, out_shape=out_shape,
         input_output_aliases=call_aliases, interpret=interpret,
-    )(*operands)
+        **named)(*operands)

@@ -66,12 +66,12 @@ def main(argv=None):
                     help="admission skip-ahead window past a "
                          "head-of-queue that does not fit")
     ap.add_argument("--telemetry", action="store_true",
-                    help="enable repro.obs metrics + serve-tick spans "
-                         "(implied by --trace-out / --prom-out / "
-                         "--metrics-jsonl)")
-    ap.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="write a Chrome trace-event JSON "
-                         "(Perfetto-loadable) at exit")
+                    help="enable repro.obs metrics (implied by "
+                         "--prom-out / --metrics-jsonl)")
+    ap.add_argument("--trace-out", default=None, metavar="DIR",
+                    help="record a jax.profiler trace of serving into DIR "
+                         "(serve.* spans beside the device's operations; "
+                         "Perfetto-loadable)")
     ap.add_argument("--prom-out", default=None, metavar="PATH",
                     help="write a Prometheus text exposition at exit")
     ap.add_argument("--metrics-jsonl", default=None, metavar="PATH",
@@ -83,8 +83,7 @@ def main(argv=None):
     compile_cache.enable()
 
     from repro import obs
-    telemetry = (args.telemetry or args.trace_out or args.prom_out
-                 or args.metrics_jsonl)
+    telemetry = args.telemetry or args.prom_out or args.metrics_jsonl
     if telemetry:
         obs.enable()
     emitter = (obs.export.JsonlEmitter(args.metrics_jsonl,
@@ -116,13 +115,14 @@ def main(argv=None):
         reqs.append(r)
         eng.submit(r)
     t0 = time.perf_counter()
-    if emitter is None:
-        eng.run()
-    else:
-        while eng.queue or eng.active.any():
-            eng.step()
-            emitter.maybe_emit()
-        emitter.emit()       # short runs still get >= 1 line
+    with obs.tracing.profile(args.trace_out):
+        if emitter is None:
+            eng.run()
+        else:
+            while eng.queue or eng.active.any():
+                eng.step()
+                emitter.maybe_emit()
+            emitter.emit()       # short runs still get >= 1 line
     dt = time.perf_counter() - t0
     total = sum(len(r.out_tokens) for r in reqs)
     print(f"[serve] {len(reqs)} requests, {total} tokens, {dt:.2f}s "
@@ -132,10 +132,9 @@ def main(argv=None):
         print(f"[serve] paged: shared={st.shared_maps} cow={st.cow_copies} "
               f"evict={st.evictions} preempt={eng.preemptions} "
               f"hit_rate={st.prefix_hit_rate():.2f}")
+    if args.trace_out:
+        print(f"[serve] trace -> {args.trace_out}")
     if telemetry:
-        if args.trace_out:
-            obs.export.write_trace(args.trace_out)
-            print(f"[serve] telemetry: trace -> {args.trace_out}")
         if args.prom_out:
             obs.export.write_prometheus(args.prom_out)
             print(f"[serve] telemetry: prometheus -> {args.prom_out}")

@@ -79,16 +79,16 @@ def main(argv=None):
                          "shard (shard_map halo exchange); pairs with "
                          "--attn-impl pallas for long-sequence training")
     ap.add_argument("--telemetry", action="store_true",
-                    help="enable repro.obs metrics + train-step spans "
-                         "(implied by --trace-out)")
-    ap.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="write a Chrome trace-event JSON "
-                         "(Perfetto-loadable) at exit")
+                    help="enable repro.obs metrics")
+    ap.add_argument("--trace-out", default=None, metavar="DIR",
+                    help="record a jax.profiler trace of the run into DIR "
+                         "(train.step spans beside the device's "
+                         "operations; Perfetto-loadable)")
     args = ap.parse_args(argv)
     compile_cache.enable()
 
     from repro import obs
-    if args.telemetry or args.trace_out:
+    if args.telemetry:
         obs.enable()
 
     dshape = tuple(int(x) for x in args.mesh.split("x"))
@@ -107,7 +107,7 @@ def main(argv=None):
     data = src_cls(vocab_size=cfg.vocab_size, seq_len=args.seq,
                    batch_per_host=args.batch, seed=args.seed)
 
-    with jax.set_mesh(mesh):
+    with jax.set_mesh(mesh), obs.tracing.profile(args.trace_out):
         state, raw_step = build(cfg, tc, mesh, sp=args.sp)
         start = ckpt.latest_step(tc.ckpt_dir) if args.ckpt_every else None
         if start is not None:
@@ -121,8 +121,7 @@ def main(argv=None):
             for step in range(int(state.step), args.steps):
                 batch = jax.tree.map(jnp.asarray, pre.next())
                 t0 = time.perf_counter()
-                with obs.span("train.step", tid=obs.TRACK_TRAIN,
-                              args={"step": step}):
+                with obs.span("train.step"):
                     state, metrics = step_fn(state, batch)
                     loss = float(metrics["loss"])
                 dt = time.perf_counter() - t0
@@ -141,8 +140,7 @@ def main(argv=None):
             pre.close()
         saver.wait()
     if args.trace_out:
-        obs.export.write_trace(args.trace_out)
-        print(f"[train] telemetry: trace -> {args.trace_out}")
+        print(f"[train] trace -> {args.trace_out}")
     print("[train] done")
     return state
 

@@ -4,35 +4,36 @@ Quickstart::
 
     from repro import obs
     obs.enable()
-    ... run serve / train / bench ...
-    obs.export.write_trace("trace.json")       # load in ui.perfetto.dev
+    with obs.tracing.profile("trace_dir"):     # load in ui.perfetto.dev
+        ... run serve / train / bench ...
     print(obs.export.prometheus_text())
     snap = obs.export.snapshot()
 
-Telemetry is OFF by default and costs one branch per instrumentation
+Spans (:func:`span`) are profiler annotations and always on: they cost
+nothing measurable unless a ``jax.profiler`` session records them, and
+then they share the device's clock (:mod:`repro.obs.tracing`).
+
+Metrics are OFF by default and cost one branch per instrumentation
 site when off (:mod:`repro.obs.metrics` returns shared no-op stubs).
 :func:`enable` flips the registry live and registers the kernel-launch
 hook on :mod:`repro.analysis.contracts`, so every ``pallas_call``
-traced while enabled is accounted (family, grid, analytic HBM bytes
-and FLOPs -- see :mod:`repro.obs.traffic`).  CLIs expose this as
-``--telemetry`` / ``--trace-out`` / ``--prom-out``.
+traced while enabled is counted per family with its analytic HBM
+bytes and FLOPs (:mod:`repro.obs.traffic`).  CLIs expose this as
+``--telemetry`` / ``--trace-out DIR`` / ``--prom-out``.
 """
 from __future__ import annotations
 
 from . import export, metrics, tracing, traffic
-from .metrics import (NULL_COUNTER, NULL_GAUGE, NULL_HISTOGRAM, NULL_SPAN,
-                      Histogram, counter, enabled, gauge, histogram,
-                      registry)
-from .tracing import (TRACK_BENCH, TRACK_KERNELS, TRACK_SERVE, TRACK_TRAIN,
-                      instant, span)
+from .metrics import (NULL_COUNTER, NULL_GAUGE, NULL_HISTOGRAM, Histogram,
+                      counter, enabled, gauge, histogram, registry)
+from .tracing import span
 
 __all__ = [
     "enable", "disable", "enabled", "reset",
-    "counter", "gauge", "histogram", "span", "instant",
+    "counter", "gauge", "histogram", "span",
     "registry", "Histogram",
     "metrics", "tracing", "traffic", "export",
-    "NULL_COUNTER", "NULL_GAUGE", "NULL_HISTOGRAM", "NULL_SPAN",
-    "TRACK_SERVE", "TRACK_TRAIN", "TRACK_BENCH", "TRACK_KERNELS",
+    "NULL_COUNTER", "NULL_GAUGE", "NULL_HISTOGRAM",
 ]
 
 _HOOKED = False
@@ -50,7 +51,7 @@ def enable() -> None:
 
 def disable() -> None:
     """Turn telemetry off (hot paths revert to the one-branch no-op).
-    Collected metrics/trace events are kept until :func:`reset`."""
+    Collected metrics are kept until :func:`reset`."""
     global _HOOKED
     metrics._set_enabled(False)
     if _HOOKED:
@@ -60,7 +61,5 @@ def disable() -> None:
 
 
 def reset() -> None:
-    """Clear all collected metrics and trace events (enabled state is
-    unchanged)."""
+    """Clear all collected metrics (enabled state is unchanged)."""
     metrics.registry().reset()
-    tracing.buffer().reset()

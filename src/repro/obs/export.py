@@ -1,18 +1,17 @@
-"""Export surfaces: snapshot dict, Prometheus text, JSONL, trace files.
+"""Export surfaces: snapshot dict, Prometheus text, JSONL.
 
-Four ways out of the in-process registry/trace buffer:
+Three ways out of the in-process registry:
 
 * :func:`snapshot` -- one JSON-able dict: metrics (counters / gauges /
-  histogram summaries), the kernel tuning state (backend, digest,
-  aggregated decision-log counts), and trace-buffer stats.
+  histogram summaries) and the kernel tuning state (backend, digest,
+  aggregated decision-log counts).
 * :func:`prometheus_text` -- Prometheus text exposition (0.0.4):
   ``repro_``-prefixed names with dots flattened to underscores,
   histograms as cumulative ``_bucket{le=...}`` series.
 * :class:`JsonlEmitter` -- appends a snapshot line to a file at most
   once per ``period_s`` (drive it from any loop; ``emit()`` forces).
-* :func:`write_trace` -- Chrome trace-event JSON via the tracing
-  buffer, with a metadata header carrying backend + XLA_FLAGS +
-  tuning_digest so every trace pins the environment it was captured in.
+
+Spans go to the ``jax.profiler`` trace instead (:mod:`.tracing`).
 
 The ``validate_*`` functions are the *pinned schemas*: tests and the CI
 telemetry smoke (``scripts/check_telemetry.py``) call the same code, so
@@ -23,10 +22,9 @@ from __future__ import annotations
 import collections
 import json
 import math
-import os
 import re
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Sequence
 
 from . import metrics as _m
 from . import tracing as _t
@@ -49,25 +47,12 @@ def tuning_snapshot() -> Dict[str, Any]:
     }
 
 
-def trace_metadata() -> Dict[str, Any]:
-    """The header every trace/snapshot carries: enough to know what
-    environment produced it."""
-    ts = tuning_snapshot()
-    return {
-        "backend": ts["backend"],
-        "tuning_digest": ts["tuning_digest"],
-        "xla_flags": os.environ.get("XLA_FLAGS", ""),
-    }
-
-
 def snapshot() -> Dict[str, Any]:
     return {
         "schema": "repro.obs.snapshot/1",
         "enabled": _m.enabled(),
         "metrics": _m.registry().snapshot(),
         "tuning": tuning_snapshot(),
-        "trace": {"events": len(_t.buffer()),
-                  "dropped": _t.buffer().dropped},
     }
 
 
@@ -133,14 +118,6 @@ def write_prometheus(path: str) -> None:
         f.write(prometheus_text())
 
 
-def write_trace(path: str,
-                extra_metadata: Optional[Dict[str, Any]] = None) -> None:
-    md = trace_metadata()
-    if extra_metadata:
-        md.update(extra_metadata)
-    _t.buffer().write(path, metadata=md)
-
-
 def write_snapshot(path: str) -> None:
     with open(path, "w") as f:
         json.dump(snapshot(), f, indent=1, sort_keys=True)
@@ -204,57 +181,17 @@ def validate_snapshot(doc: Dict[str, Any]) -> List[str]:
     return errs
 
 
-def validate_chrome_trace(doc: Dict[str, Any],
-                          require_kernel_traffic: bool = False,
-                          ) -> List[str]:
-    """Schema errors for a Chrome trace-event document ([] when valid).
-
-    Pins the Perfetto-loadable shape: a ``traceEvents`` array whose
-    entries carry ``ph``; ``X`` events need name/ts/dur/pid/tid; the
-    metadata header must carry backend + tuning_digest (12-hex) +
-    xla_flags.  With ``require_kernel_traffic``, at least one
-    ``kernel.launch`` instant event must carry the analytic
-    ``hbm_read_bytes``/``hbm_write_bytes``/``flops`` args.
-    """
-    errs: List[str] = []
-    evs = doc.get("traceEvents")
-    if not isinstance(evs, list) or not evs:
-        return ["traceEvents: missing or empty"]
-    md = doc.get("metadata")
-    if not isinstance(md, dict):
-        errs.append("metadata: not a dict")
-    else:
-        for field in ("backend", "tuning_digest", "xla_flags"):
-            if field not in md:
-                errs.append(f"metadata: missing {field!r}")
-        if not re.fullmatch(r"[0-9a-f]{12}",
-                            str(md.get("tuning_digest", ""))):
-            errs.append("metadata.tuning_digest not 12-hex")
-    saw_traffic = False
-    for i, ev in enumerate(evs):
-        ph = ev.get("ph")
-        if ph not in ("X", "i", "M", "B", "E", "C"):
-            errs.append(f"event {i}: bad ph {ph!r}")
-            continue
-        if ph == "X":
-            for field in ("name", "ts", "dur", "pid", "tid"):
-                if field not in ev:
-                    errs.append(f"event {i} ({ev.get('name')}): "
-                                f"X missing {field!r}")
-            if ev.get("dur", 0) < 0:
-                errs.append(f"event {i}: negative dur")
-        if ph == "i" and ev.get("name") == "kernel.launch":
-            args = ev.get("args", {})
-            need = ("family", "hbm_read_bytes", "hbm_write_bytes",
-                    "flops")
-            if all(k in args for k in need):
-                saw_traffic = True
-            else:
-                errs.append(f"event {i}: kernel.launch missing "
-                            f"traffic args {need}")
-    if require_kernel_traffic and not saw_traffic:
-        errs.append("no kernel.launch event with analytic traffic args")
-    return errs
+def validate_trace_dir(trace_dir: str,
+                       require_spans: Sequence[str] = ()) -> List[str]:
+    """Errors for a profiler trace directory ([] when valid): it holds
+    an ``.xplane.pb`` whose host planes carry every span named in
+    ``require_spans``."""
+    files = _t.xplane_files(trace_dir)
+    if not files:
+        return [f"no .xplane.pb under {trace_dir}"]
+    seen = {name for f in files for name, _, _ in _t.host_spans(f)}
+    return [f"span {name!r} not on the host plane"
+            for name in require_spans if name not in seen]
 
 
 _PROM_LINE = re.compile(

@@ -180,20 +180,9 @@ class _NullHistogram:
         pass
 
 
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
 NULL_COUNTER = _NullCounter()
 NULL_GAUGE = _NullGauge()
 NULL_HISTOGRAM = _NullHistogram()
-NULL_SPAN = _NullSpan()
 
 
 # -- registry ----------------------------------------------------------------

@@ -45,7 +45,6 @@ import numpy as np
 from repro.analysis.contracts import LaunchContract, Operand
 
 from . import metrics as _m
-from . import tracing as _t
 
 # beyond this many grid steps, skip index-map evaluation and use the
 # conservative one-fetch-per-step closed form
@@ -171,24 +170,12 @@ def contract_flops(contract: LaunchContract) -> int:
 
 def on_launch(contract: LaunchContract) -> None:
     """Launch hook (registered by ``obs.enable()``): account one traced
-    ``pallas_call`` into counters and the kernel trace track."""
+    ``pallas_call`` into the ``kernel.*`` counters of its family."""
     traffic = contract_hbm_bytes(contract)
-    flops = contract_flops(contract)
     fam = contract.family
     _m.counter("kernel.launches", family=fam).inc()
     _m.counter("kernel.hbm_read_bytes", family=fam).inc(
         traffic["read_bytes"])
     _m.counter("kernel.hbm_write_bytes", family=fam).inc(
         traffic["write_bytes"])
-    _m.counter("kernel.flops", family=fam).inc(flops)
-    args = {
-        "family": fam,
-        "grid": list(contract.grid),
-        "hbm_read_bytes": traffic["read_bytes"],
-        "hbm_write_bytes": traffic["write_bytes"],
-        "flops": flops,
-    }
-    for k in ("impl", "tq", "mode", "nr", "Lmax", "levels"):
-        if k in contract.meta:
-            args[k] = contract.meta[k]
-    _t.instant("kernel.launch", tid=_t.TRACK_KERNELS, args=args)
+    _m.counter("kernel.flops", family=fam).inc(contract_flops(contract))

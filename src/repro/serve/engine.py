@@ -683,50 +683,70 @@ class ServeEngine:
     # -- tick ----------------------------------------------------------
     def step(self) -> int:
         """One engine tick: admit + one decode step for all active slots.
-        Returns number of active slots."""
-        with obs.span("serve.tick", tid=obs.TRACK_SERVE):
+        Returns number of active slots.
+
+        Each phase of the tick is a profiler span inside ``serve.tick``:
+        ``serve.admit``; ``serve.prepare`` (page allocation and copies)
+        and ``serve.tables`` (page tables built and sent) when paged;
+        ``serve.decode``, the jitted tick's call (argument transfer,
+        output allocation, enqueue); ``serve.sample`` (next tokens and
+        positions); ``serve.readback``, the host's wait for the sampled
+        tokens; ``serve.bookkeep`` (per-slot outputs, done checks and
+        the chunked-prefill feed)."""
+        with obs.span("serve.tick"):
             n = self._step()
         if obs.enabled():
             self._tick_obs(n)
         return n
 
     def _step(self) -> int:
-        with obs.span("serve.admit", tid=obs.TRACK_SERVE):
+        with obs.span("serve.admit"):
             self._admit()
         if not self.active.any():
             return 0
         if self.paged:
-            with obs.span("serve.prepare", tid=obs.TRACK_SERVE):
+            with obs.span("serve.prepare"):
                 self._paged_prepare()
             if not self.active.any():        # everything preempted
                 return 0
-            tabs = self.pool.build_tables(self.pos_host, self.active,
-                                          self.cfg.num_kv_heads)
-            with obs.span("serve.decode", tid=obs.TRACK_SERVE):
+            with obs.span("serve.tables"):
+                tabs = self.pool.build_tables(self.pos_host, self.active,
+                                              self.cfg.num_kv_heads)
+            with obs.span("serve.decode"):
                 logits, self.caches = self._decode(self.params,
                                                    self.caches,
                                                    self.tokens, self.pos,
                                                    tabs)
         else:
-            with obs.span("serve.decode", tid=obs.TRACK_SERVE):
+            with obs.span("serve.decode"):
                 logits, self.caches = self._decode(self.params,
                                                    self.caches,
                                                    self.tokens, self.pos)
-        if self.greedy:
-            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-        else:
-            self.key, k = jax.random.split(self.key)
-            nxt = jax.random.categorical(k, logits).astype(jnp.int32)
-        self.tokens = nxt
-        # Freeze finished/inactive slots: only slots active for THIS
-        # decode advance.  A free-running pos eventually walks past the
-        # cache rows, where the clamped cache writes would grind on the
-        # last row every tick (and pos itself overflows); pinning t
-        # keeps every write in range until the slot is re-admitted.
-        act = self.active.astype(np.int32)
-        self.pos = self.pos + jnp.asarray(act)
-        self.pos_host += act     # mirrors the device update exactly
-        nxt_host = np.asarray(nxt)
+        with obs.span("serve.sample"):
+            if self.greedy:
+                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+            else:
+                self.key, k = jax.random.split(self.key)
+                nxt = jax.random.categorical(k, logits).astype(jnp.int32)
+            self.tokens = nxt
+            # Freeze finished/inactive slots: only slots active for THIS
+            # decode advance.  A free-running pos eventually walks past
+            # the cache rows, where the clamped cache writes would grind
+            # on the last row every tick (and pos itself overflows);
+            # pinning t keeps every write in range until the slot is
+            # re-admitted.
+            act = self.active.astype(np.int32)
+            self.pos = self.pos + jnp.asarray(act)
+            self.pos_host += act     # mirrors the device update exactly
+        with obs.span("serve.readback"):
+            nxt_host = np.asarray(nxt)
+        with obs.span("serve.bookkeep"):
+            self._bookkeep(nxt_host)
+        return int(self.active.sum())
+
+    def _bookkeep(self, nxt_host: np.ndarray) -> None:
+        """Append each active slot's token, release finished slots and
+        feed the next prompt token of slots still prefilling."""
         feed_idx: List[int] = []
         feed_tok: List[int] = []
         for s in range(self.slots):
@@ -753,7 +773,6 @@ class ServeEngine:
             self.tokens = self.tokens.at[jnp.asarray(
                 np.array(feed_idx, np.int32))].set(
                 jnp.asarray(np.array(feed_tok, np.int32)))
-        return int(self.active.sum())
 
     def run(self) -> None:
         while self.queue or self.active.any():

@@ -177,8 +177,7 @@ def train(cfg: ModelConfig, tc: TrainConfig, data_source, num_steps: int,
     for step in range(step0, num_steps):
         batch = jax.tree.map(jnp.asarray, data_source.batch(step))
         t0 = time.perf_counter()
-        with obs.span("train.step", tid=obs.TRACK_TRAIN,
-                      args={"step": step}):
+        with obs.span("train.step"):
             state, metrics = train_step(state, batch)
             jax.block_until_ready(metrics["loss"])
         dt = time.perf_counter() - t0

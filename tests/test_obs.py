@@ -1,6 +1,7 @@
 """Telemetry layer: disabled-path no-op guarantees, pinned export
-schemas, and the analytic HBM/FLOP accounting cross-checked against the
-EXPERIMENTS.md P25/P27 hand arithmetic.
+schemas, spans and named kernels in the profiler's trace, and the
+analytic HBM/FLOP accounting cross-checked against the EXPERIMENTS.md
+P25/P27 hand arithmetic.
 
 The analytic-traffic tests are the paper-notebook numbers as executable
 code: the P25 decode-tick figure (one fused attend launch reads
@@ -11,6 +12,9 @@ must both be reproduced by the generic traffic model in
 """
 import json
 import math
+import os
+import re
+import sys
 import time
 
 import jax
@@ -21,7 +25,11 @@ import pytest
 from repro import obs
 from repro.analysis import contracts
 from repro.configs import get_smoke_config
-from repro.obs import export, metrics, traffic
+from repro.obs import export, metrics, tracing, traffic
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:            # the benchmark's trace reader
+    sys.path.insert(0, ROOT)
 
 pytestmark = []
 
@@ -46,16 +54,13 @@ def test_disabled_accessors_return_shared_stubs():
     assert obs.counter("other", family="x") is obs.NULL_COUNTER
     assert obs.gauge("pool.occupancy") is obs.NULL_GAUGE
     assert obs.histogram("serve.ttft_s") is obs.NULL_HISTOGRAM
-    assert obs.span("serve.tick") is obs.NULL_SPAN
     obs.counter("serve.ticks").inc()
     obs.gauge("pool.occupancy").set(0.5)
     obs.histogram("serve.ttft_s").observe(1.0)
-    with obs.span("serve.tick"):
+    with obs.span("serve.tick"):         # spans never touch the registry
         pass
-    obs.instant("kernel.launch")
     snap = metrics.registry().snapshot()
     assert snap == {"counters": {}, "gauges": {}, "histograms": {}}
-    assert len(obs.tracing.buffer()) == 0
 
 
 def test_disabled_overhead_is_tiny():
@@ -79,7 +84,116 @@ def test_disabled_launches_record_no_metrics():
     c = _capture_decode_contract(Lmax=64, nr=8, d=16, G=2, R=2)
     assert c is not None
     assert metrics.registry().snapshot()["counters"] == {}
-    assert len(obs.tracing.buffer()) == 0
+
+
+def test_span_cost_without_profiler():
+    """1e5 span enters and exits with no profiler session recording in
+    well under a second: spans stay on in every run."""
+    n = 100_000
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with obs.span("serve.tick"):
+            pass
+    dt = time.perf_counter() - t0
+    assert dt < 2.0, f"{n} spans took {dt:.2f}s"
+
+
+# -- spans and named kernels in the profiler's trace -------------------------
+
+def _profiled_host_events(tmp_path, body):
+    """Run ``body`` under ``tracing.profile`` and return the host events
+    of the trace as the benchmark's reduction reads them."""
+    from bench import reduce_trace
+    d = str(tmp_path / "trace")
+    with tracing.profile(d):
+        body()
+    (path,) = tracing.xplane_files(d)
+    _, host = reduce_trace.events(path)
+    return host
+
+
+def test_span_lands_in_profiler_trace_and_nests(tmp_path):
+    def body():
+        with obs.span("serve.tick"):
+            with obs.span("serve.decode"):
+                jnp.ones(4).block_until_ready()
+            with obs.span("serve.readback"):
+                pass
+    host = _profiled_host_events(tmp_path, body)
+    got = {n: (s, e) for n, s, e in host
+           if n in ("serve.tick", "serve.decode", "serve.readback")}
+    assert set(got) == {"serve.tick", "serve.decode", "serve.readback"}
+    tick = got["serve.tick"]
+    for child in ("serve.decode", "serve.readback"):
+        assert tick[0] <= got[child][0] <= got[child][1] <= tick[1]
+    assert got["serve.decode"][1] <= got["serve.readback"][0]
+    # telemetry stayed off: spans need no registry
+    assert metrics.registry().snapshot()["counters"] == {}
+
+
+def test_trace_dir_validator(tmp_path):
+    def body():
+        with obs.span("serve.tick"):
+            pass
+    d = str(tmp_path / "trace")
+    with tracing.profile(d):
+        body()
+    assert export.validate_trace_dir(d, require_spans=("serve.tick",)) == []
+    assert export.validate_trace_dir(d, require_spans=("serve.tables",))
+    (tmp_path / "empty").mkdir()
+    assert export.validate_trace_dir(str(tmp_path / "empty"))
+    assert any(f.endswith("perfetto_trace.json.gz")
+               for _, _, fs in os.walk(d) for f in fs)
+
+
+def _band_fwd_launch():
+    from repro.kernels import ops
+    S, f32 = jax.ShapeDtypeStruct, jnp.float32
+    B, G, L, d = 1, 4, 256, 128
+    return (lambda q, k, v, w: ops.band_attention(
+        q, k, v, w, nr=16, mode="l0_causal", ratio=1, impl="pallas"),
+        (S((B, G, L, d), f32), S((B, L, d), f32), S((B, L, d), f32),
+         S((B, L), f32)))
+
+
+def _decode_attend_paged_launch():
+    from repro.core import h1d_decode as hd
+    from repro.core import hierarchy as hc
+    S, nr = jax.ShapeDtypeStruct, 16
+    R, G, Lmax, d = 8, 4, 256, 128
+    levels = hc.num_levels(Lmax, nr) - 1
+    pool = jax.eval_shape(lambda: hd.init_paged_pool(
+        [R * (Lmax >> l) // nr for l in range(levels + 1)], nr, d, d,
+        dtype=jnp.float32))
+    return (lambda c, q, t, a: hd.decode_attend_paged(
+        c, q, t, a, nr=nr, impl="pallas"),
+        (pool, S((R, G, d), jnp.float32), S((R,), jnp.int32),
+         S((R, 2 + levels), jnp.int32)))
+
+
+_LAUNCHES = {"band_fwd": _band_fwd_launch,
+             "decode_attend_paged": _decode_attend_paged_launch}
+
+
+def _tpu_lowering(family) -> str:
+    fn, args = _LAUNCHES[family]()
+    return jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("family", sorted(_LAUNCHES))
+def test_launch_names_kernel_in_tpu_lowering(family):
+    """Every Mosaic call ``contracts.launch`` issues carries its family
+    in ``kernel_metadata``, the attribute a device trace prints in the
+    kernel event's name."""
+    text = _tpu_lowering(family)
+    calls = text.count("@tpu_custom_call")
+    assert calls >= 1
+    meta = re.findall(r'kernel_metadata = "([^"]*)"', text)
+    assert len(meta) == calls
+    assert all(re.search(r'\\22family\\22:\\22' + family + r'\\22', m)
+               for m in meta), meta
+    assert re.findall(r'kernel_name = "(\w+)"', text) == [family] * calls
 
 
 # -- enabled path ------------------------------------------------------------
@@ -137,13 +251,6 @@ def _populate():
     obs.gauge("pool.occupancy").set(0.5)
     for v in (1e-3, 2e-3, 5e-3):
         obs.histogram("serve.ttft_s").observe(v)
-    with obs.span("serve.tick", tid=obs.TRACK_SERVE, args={"n": 2}):
-        with obs.span("serve.decode", tid=obs.TRACK_SERVE):
-            pass
-    obs.instant("kernel.launch", tid=obs.TRACK_KERNELS,
-                args={"family": "decode_attend", "grid": [4],
-                      "hbm_read_bytes": 1024, "hbm_write_bytes": 64,
-                      "flops": 2048})
 
 
 def test_snapshot_schema_pinned():
@@ -198,30 +305,6 @@ def test_prometheus_text_schema_pinned():
     assert counts[-1] == 3
     # drift guard: a malformed line fails the validator
     assert export.validate_prometheus_text("bad line here\n")
-
-
-def test_chrome_trace_schema_pinned(tmp_path):
-    _populate()
-    path = tmp_path / "trace.json"
-    export.write_trace(str(path))
-    doc = json.loads(path.read_text())
-    assert export.validate_chrome_trace(
-        doc, require_kernel_traffic=True) == []
-    evs = doc["traceEvents"]
-    names = {e["name"] for e in evs}
-    assert {"serve.tick", "serve.decode", "kernel.launch",
-            "thread_name", "process_name"} <= names
-    # every track used is named via "M" metadata (Perfetto lanes)
-    tracks = {e["args"]["name"] for e in evs if e["ph"] == "M"
-              and e["name"] == "thread_name"}
-    assert {"serve", "train", "bench", "kernels"} <= tracks
-    # the environment header pins what produced the trace
-    assert doc["metadata"]["backend"] == jax.default_backend()
-    # drift guard: stripping the traffic args fails the strict check
-    for e in evs:
-        if e["name"] == "kernel.launch":
-            del e["args"]["flops"]
-    assert export.validate_chrome_trace(doc, require_kernel_traffic=True)
 
 
 def test_jsonl_emitter(tmp_path):
@@ -354,8 +437,8 @@ def test_p27_fixed_hbm_budget_hand_accounting():
 
 def test_launch_hook_feeds_registry_and_trace():
     """With telemetry on, a traced launch lands as kernel.* counters
-    AND a kernel.launch instant whose analytic args agree with the
-    direct traffic-model call."""
+    that agree with the direct traffic-model call, and the same launch
+    names its family where the device trace reads it."""
     obs.enable()
     c = _capture_decode_contract(Lmax=256, nr=8, d=16, G=2, R=4)
     snap = metrics.registry().snapshot()["counters"]
@@ -365,13 +448,12 @@ def test_launch_hook_feeds_registry_and_trace():
         == tr["read_bytes"]
     assert snap["kernel.hbm_write_bytes{family=decode_attend}"] \
         == tr["write_bytes"]
-    doc = obs.tracing.buffer().chrome_trace(export.trace_metadata())
-    launches = [e for e in doc["traceEvents"]
-                if e["name"] == "kernel.launch"]
-    assert launches and launches[0]["args"]["family"] == "decode_attend"
-    assert launches[0]["args"]["hbm_read_bytes"] == tr["read_bytes"]
-    assert export.validate_chrome_trace(
-        doc, require_kernel_traffic=True) == []
+    assert snap["kernel.flops{family=decode_attend}"] \
+        == traffic.contract_flops(c)
+    text = _tpu_lowering("decode_attend_paged")
+    snap = metrics.registry().snapshot()["counters"]
+    assert snap["kernel.launches{family=decode_attend_paged}"] == 1
+    assert 'kernel_name = "decode_attend_paged"' in text
 
 
 # -- serve-path integration --------------------------------------------------
@@ -393,12 +475,12 @@ def _tiny_engine(paged):
 
 
 @pytest.mark.slow
-def test_serve_engine_emits_ticks_latencies_and_pool_counters():
+def test_serve_engine_emits_ticks_latencies_and_pool_counters(tmp_path):
     obs.enable()
     eng, reqs = _tiny_engine(paged=True)
     for r in reqs:
         eng.submit(r)
-    eng.run()
+    host = _profiled_host_events(tmp_path, eng.run)
     assert all(len(r.out_tokens) == 4 for r in reqs)
     snap = export.snapshot()
     cs, hs = snap["metrics"]["counters"], snap["metrics"]["histograms"]
@@ -416,23 +498,40 @@ def test_serve_engine_emits_ticks_latencies_and_pool_counters():
     assert cs.get("pool.prefix_hits", 0) >= 1
     assert "pool.occupancy" in snap["metrics"]["gauges"]
     assert "serve.token_budget_util" in snap["metrics"]["gauges"]
-    # serve.tick spans cover every engine tick
-    ticks = [e for e in obs.tracing.buffer().chrome_trace()
-             ["traceEvents"] if e.get("name") == "serve.tick"]
+    # serve.tick spans in the profiler's trace cover every engine tick
+    ticks = [n for n, _, _ in host if n == "serve.tick"]
     assert len(ticks) == cs["serve.ticks"]
     assert export.validate_snapshot(snap) == []
 
 
 @pytest.mark.slow
-def test_serve_engine_disabled_leaves_no_telemetry():
+def test_serve_engine_disabled_leaves_no_telemetry(tmp_path):
+    """Metrics off: the registry stays empty.  Spans are on regardless,
+    so a profiler trace still holds every phase of the dense tick."""
     eng, reqs = _tiny_engine(paged=False)
     for r in reqs:
         eng.submit(r)
-    eng.run()
+    host = _profiled_host_events(tmp_path, eng.run)
     assert all(len(r.out_tokens) == 4 for r in reqs)
     assert metrics.registry().snapshot() == {
         "counters": {}, "gauges": {}, "histograms": {}}
-    assert len(obs.tracing.buffer()) == 0
+    names = {n for n, _, _ in host}
+    assert {"serve.tick", "serve.admit", "serve.decode", "serve.sample",
+            "serve.readback", "serve.bookkeep"} <= names
+
+
+def test_serve_cli_trace_out_records_engine_spans(tmp_path):
+    """``launch/serve.py --trace-out DIR`` writes a profiler trace whose
+    host plane carries every phase of the paged tick."""
+    from repro.launch import serve
+    d = str(tmp_path / "trace")
+    serve.main(["--smoke", "--requests", "2", "--slots", "2",
+                "--new-tokens", "2", "--max-len", "64", "--paged",
+                "--trace-out", d])
+    assert export.validate_trace_dir(d, require_spans=(
+        "serve.tick", "serve.admit", "serve.prepare", "serve.tables",
+        "serve.decode", "serve.sample", "serve.readback",
+        "serve.bookkeep")) == []
 
 
 def test_pool_stats_snapshot_and_reset():
