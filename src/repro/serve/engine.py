@@ -656,6 +656,7 @@ class ServeEngine:
                 self.caches = pc.apply_copies(self.caches, copies,
                                               self.cfg.num_kv_heads,
                                               self._stacked)
+                self.pool.stats.copy_launches += 1
                 copies = {}
 
         order = sorted((serial, s) for s, serial in

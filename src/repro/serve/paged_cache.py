@@ -72,13 +72,16 @@ class PoolStats:
     count LOOKUPS against the prefix registry during prefix-sharing
     admissions (one per page span), so ``prefix_hit_rate()`` is a true
     rate; ``shared_maps`` keeps counting the hit *mappings* for
-    backward compatibility (equal to ``prefix_hits`` in practice)."""
+    backward compatibility (equal to ``prefix_hits`` in practice).
+    ``copy_launches`` counts the engine's flushes of page copies, one
+    device program each (:func:`apply_copies`)."""
     cow_copies: int = 0
     evictions: int = 0
     shared_maps: int = 0
     fresh_pages: int = 0
     prefix_hits: int = 0
     prefix_misses: int = 0
+    copy_launches: int = 0
 
     def snapshot(self) -> Dict[str, int]:
         return dataclasses.asdict(self)
@@ -485,11 +488,56 @@ def _map_layers(caches, stacked: bool, fn):
     return [fn(c) for c in caches]
 
 
+def _copy_table_shape(caches, Hkv: int, stacked: bool) -> Tuple[int, int]:
+    """(levels, entries per level) of a flush's copy table.  Entries:
+    the usable pages of the pool's smallest level (all but ZERO and
+    TRASH), plus one.  A flush's copies (after the last-writer dedup)
+    land on distinct private write-set pages of distinct slots; every
+    slot prepared since the last flush holds one such page on every
+    level, except at most one whose preparation ran out of pages and
+    forced the flush, so no level takes more."""
+    c = caches if stacked else caches[0]
+    rows = [a.shape[int(stacked)] for a in (c.k, *c.ck)]
+    return len(rows), min(rows) // Hkv - 1
+
+
+def _copy_pages(caches, table, Hkv: int, stacked: bool):
+    """Every level's page copies of one flush, in place.  ``table``:
+    (levels, n, 2) int32 ``(src_page, dst_page)``; a negative
+    ``dst_page`` marks an unused entry, which writes nothing."""
+    heads = jnp.arange(Hkv, dtype=jnp.int32)
+
+    def per_level(l, ka, va):
+        src = (table[l, :, :1] * Hkv + heads).reshape(-1)
+        dst = table[l, :, 1:]
+        dst = jnp.where(dst >= 0, dst * Hkv + heads,
+                        ka.shape[int(stacked)]).reshape(-1)
+
+        def copy(a):
+            if stacked:
+                return a.at[:, dst].set(a[:, src], mode="drop")
+            return a.at[dst].set(a[src], mode="drop")
+        return copy(ka), copy(va)
+
+    # scale arrays share the physical-row axis, so the same row copy
+    # applies (a page's scales travel with its int8 payload)
+    return _map_layers(caches, stacked,
+                       lambda c: _per_level(c, per_level, per_level))
+
+
+# one program per pool: the table's shape is fixed by the pool, so no
+# count of copies or mix of levels compiles again; the caches are
+# donated, so each scatter writes its pages in place
+_copy_program = jax.jit(_copy_pages, donate_argnums=0,
+                        static_argnums=(2, 3))
+
+
 def apply_copies(caches, copies: Dict[int, List[Tuple[int, int]]],
                  Hkv: int, stacked: bool):
-    """Batched page copies (COW + zero-init): for each level, one
-    gather/scatter over the expanded physical rows.  ``copies`` maps
-    level -> [(src_page, dst_page)].
+    """Batched page copies (COW + zero-init) of one flush: ``copies``
+    maps level -> [(src_page, dst_page)].  All levels go to the device
+    as one table in one donated program (``_copy_program``), so the
+    caches passed in are consumed and the returned ones replace them.
 
     A mid-tick preemption can free a page that already has a pending
     copy and hand it to a later allocation, which schedules its own
@@ -498,27 +546,17 @@ def apply_copies(caches, copies: Dict[int, List[Tuple[int, int]]],
     stale one targeted a page its owner no longer holds)."""
     if not copies:
         return caches
-    idx = {}
+    levels, n = _copy_table_shape(caches, Hkv, stacked)
+    table = np.full((levels, n, 2), -1, np.int32)
+    table[:, :, 0] = ZERO
     for l, pairs in copies.items():
         last = {d: s for s, d in pairs}          # last writer per dst
-        pairs = [(s, d) for d, s in last.items()]
-        src = np.concatenate([np.arange(Hkv) + s * Hkv for s, _ in pairs])
-        dst = np.concatenate([np.arange(Hkv) + d * Hkv for _, d in pairs])
-        idx[l] = (jnp.asarray(src), jnp.asarray(dst))
-
-    def per_level(l, ka, va):
-        if l not in idx:
-            return ka, va
-        src, dst = idx[l]
-        if stacked:
-            return (ka.at[:, dst].set(ka[:, src]),
-                    va.at[:, dst].set(va[:, src]))
-        return ka.at[dst].set(ka[src]), va.at[dst].set(va[src])
-
-    # scale arrays share the physical-row axis, so the same row copy
-    # applies (a page's scales travel with its int8 payload)
-    return _map_layers(caches, stacked,
-                       lambda c: _per_level(c, per_level, per_level))
+        assert len(last) <= n, (
+            f"{len(last)} page copies at level {l} in one flush; the "
+            f"pool's table holds {n}")
+        for i, (d, s) in enumerate(last.items()):
+            table[l, i] = (s, d)
+    return _copy_program(caches, table, Hkv, stacked)
 
 
 def scatter_prefill(caches, dense_caches,

@@ -118,6 +118,7 @@ def test_prefix_sharing_and_cow_with_token_parity():
     assert out == ref
     assert eng.pool.stats.shared_maps > 0
     assert eng.pool.stats.cow_copies > 0        # divergent writes COW'd
+    assert eng.pool.stats.copy_launches > 0     # one per flush
 
 
 def test_eviction_under_pool_pressure():
@@ -538,3 +539,142 @@ def test_int8_snapshot_restore_roundtrip_and_dtype_guard():
     pool3, caches3 = mk(0)                    # fp32 pool
     with pytest.raises(ValueError, match="dtype"):
         pc.restore_slot(caches3, pool3, 0, snap, Hkv, stacked=False)
+
+
+# ---------------------------------------------------------------------------
+# page copies: one donated program per flush
+# ---------------------------------------------------------------------------
+
+def _eager_apply_copies(caches, copies, Hkv, stacked):
+    """The eager page copy the batched program replaced: per level one
+    gather and one scatter over the expanded physical rows, last writer
+    per destination kept.  The oracle for ``pc.apply_copies``."""
+    idx = {}
+    for l, pairs in copies.items():
+        last = {d: s for s, d in pairs}
+        pairs = [(s, d) for d, s in last.items()]
+        src = np.concatenate([np.arange(Hkv) + s * Hkv for s, _ in pairs])
+        dst = np.concatenate([np.arange(Hkv) + d * Hkv for _, d in pairs])
+        idx[l] = (src, dst)
+
+    def per_level(l, ka, va):
+        if l not in idx:
+            return ka, va
+        src, dst = idx[l]
+        if stacked:
+            return (ka.at[:, dst].set(ka[:, src]),
+                    va.at[:, dst].set(va[:, src]))
+        return ka.at[dst].set(ka[src]), va.at[dst].set(va[src])
+
+    return pc._map_layers(caches, stacked,
+                          lambda c: pc._per_level(c, per_level, per_level))
+
+
+def _random_pool_caches(key, pool, Hkv, stacked, layers=2, D=4):
+    """Device caches for ``pool`` filled with random bytes (payloads and
+    scales), stacked over ``layers`` or as a per-layer list."""
+    import jax.numpy as jnp
+    from repro.core import h1d_decode as hd
+    rows = [n * Hkv for n in pool.num_pages]
+    if any(pool.quant):
+        one = hd.init_quant_paged_pool(rows, pool.nr, D, D,
+                                       quant=tuple(pool.quant))
+    else:
+        one = hd.init_paged_pool(rows, pool.nr, D, D)
+    lead = (layers,) if stacked else ()
+    leaves, tree = jax.tree.flatten(one)
+    keys = jax.random.split(key, len(leaves) * layers)
+
+    def fill(a, k):
+        shape = lead + a.shape
+        if a.dtype == jnp.int8:
+            return jax.random.randint(k, shape, -127, 128, jnp.int8)
+        return jax.random.normal(k, shape, a.dtype)
+
+    if stacked:
+        return jax.tree.unflatten(tree, [fill(a, k) for a, k in
+                                         zip(leaves, keys)])
+    return [jax.tree.unflatten(tree, [fill(a, k) for a, k in zip(
+        leaves, keys[i * len(leaves):(i + 1) * len(leaves)])])
+            for i in range(layers)]
+
+
+def _random_copies(rng, pool, n):
+    """A random flush: a random subset of levels, each with 1..n
+    distinct destinations, sources drawn from ZERO and live pages
+    (COW), and some destinations scheduled twice (the dedup keeps the
+    last)."""
+    copies = {}
+    levels = rng.choice(pool.M, int(rng.integers(1, pool.M + 1)),
+                        replace=False)
+    for l in levels:
+        pages = np.arange(2, pool.num_pages[l])
+        dsts = rng.choice(pages, int(rng.integers(1, min(n, len(pages))
+                                                  + 1)), replace=False)
+        pairs = []
+        for d in dsts:
+            for _ in range(int(rng.integers(1, 3))):   # repeats
+                src = pc.ZERO if rng.random() < 0.5 else int(
+                    rng.choice(pages))
+                pairs.append((src, int(d)))
+        copies[int(l)] = pairs
+    return copies
+
+
+_POOLS = {"fp32": 0, "int8": -1, "int8_fine": 1}
+
+
+@pytest.mark.parametrize("stacked", [True, False],
+                         ids=["stacked", "per_layer"])
+@pytest.mark.parametrize("kind", list(_POOLS))
+def test_apply_copies_matches_eager_oracle(kind, stacked):
+    """The one donated copy program leaves every pool leaf (payloads
+    and scales, every level, every layer) bit-identical to the eager
+    per-level gather/scatter it replaced, over random flushes of ZERO
+    and COW sources with repeated destinations."""
+    Hkv = 2
+    pool = pc.PagePool(slots=3, max_len=64, nr=8, pool_pages=16,
+                       quant_levels=_POOLS[kind])
+    rng = np.random.default_rng(len(kind) + stacked)
+    shape = pc._copy_table_shape(
+        _random_pool_caches(jax.random.PRNGKey(0), pool, Hkv, stacked),
+        Hkv, stacked)
+    assert shape == (pool.M, min(pool.num_pages) - 1)
+    n = shape[1]
+    for trial in range(3):
+        caches = _random_pool_caches(jax.random.PRNGKey(trial), pool,
+                                     Hkv, stacked)
+        copies = _random_copies(rng, pool, n)
+        want = jax.tree.map(np.asarray,
+                            _eager_apply_copies(caches, copies, Hkv,
+                                                stacked))
+        got = pc.apply_copies(caches, copies, Hkv, stacked)
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a), b)
+
+
+def test_apply_copies_compiles_once_and_donates():
+    """Copy counts 1..slots over any mix of levels run through ONE
+    compiled program per pool, and the caches handed in are consumed
+    (donated) by every call."""
+    Hkv = 2
+    pool = pc.PagePool(slots=4, max_len=128, nr=8, pool_pages=32)
+    rng = np.random.default_rng(5)
+    caches = _random_pool_caches(jax.random.PRNGKey(1), pool, Hkv, True)
+    pc._copy_program.clear_cache()
+    for count in range(1, pool.slots + 1):
+        for _ in range(2):
+            levels = rng.choice(pool.M, int(rng.integers(1, pool.M + 1)),
+                                replace=False)
+            copies = {int(l): [(pc.ZERO, 2 + i) for i in range(count)]
+                      for l in levels}
+            old = jax.tree.leaves(caches)
+            caches = pc.apply_copies(caches, copies, Hkv, True)
+            assert all(a.is_deleted() for a in old)
+    assert pc._copy_program._cache_size() == 1
+    with pytest.raises(AssertionError, match="table holds"):
+        n = pc._copy_table_shape(caches, Hkv, True)[1]
+        pc.apply_copies(caches, {0: [(pc.ZERO, 2 + i)
+                                     for i in range(n + 1)]}, Hkv, True)

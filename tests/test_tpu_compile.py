@@ -132,24 +132,11 @@ def test_decode_attend_update_compiles(one_chip, cache_kind, d, dtype):
                    *_decode_args(one_chip, cache_kind, d, dtype))
 
 
-# head_dim 128 only: the TPU lays out an array whose minor dim is 64 with
-# that dim off the lanes, so every kernel over a head_dim-64 cache pays
-# a relayout of it (PERF.md, open questions)
-@pytest.mark.parametrize("cache_kind", ["dense", "paged", "int8_paged"])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_decode_step_updates_cache_in_place(one_chip, cache_kind, dtype):
-    """With the cache donated, a decode tick moves O(rows) bytes of the
-    K/V levels: no copy or transpose of any level-sized array, and every
-    cache leaf aliased input -> output.  (The int8 pool's (NP, nr) scale
-    arrays, 1/32 of its bytes at head_dim 128, are not held to this: the
-    TPU keeps them lane-transposed, and the attend's row gather
-    relayouts them -- PERF.md, open questions.)"""
-    args = _decode_args(one_chip, cache_kind, 128, dtype)
-    leaves = jax.tree.leaves(args[0])
-    text = jax.jit(_decode_step(cache_kind), donate_argnums=0).lower(
-        *args).compile().as_text()
-    assert "tpu_custom_call" in text
-    levels = [a for a in leaves if a.ndim == 3]
+def _assert_cache_in_place(text, leaves, ndim):
+    """No copy or transpose of a level-sized array (a cache leaf of
+    ``ndim`` dims) in the compiled ``text``, and every cache leaf, the
+    program's first parameters, aliased to the same output."""
+    levels = [a for a in leaves if a.ndim == ndim]
     big = {tuple(a.shape) for a in levels}
     big |= {(*a.shape[:-2], a.shape[-2] // 2, 2, a.shape[-1])
             for a in levels}
@@ -165,3 +152,42 @@ def test_decode_step_updates_cache_in_place(one_chip, cache_kind, dtype):
                re.findall(r"\{(\d+)\}: \((\d+), \{", header)}
     want = {(i, i) for i in range(len(leaves))}
     assert want <= aliased, sorted(want - aliased)
+
+
+# head_dim 128 only: the TPU lays out an array whose minor dim is 64 with
+# that dim off the lanes, so every kernel over a head_dim-64 cache pays
+# a relayout of it (PERF.md, open questions)
+@pytest.mark.parametrize("cache_kind", ["dense", "paged", "int8_paged"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_step_updates_cache_in_place(one_chip, cache_kind, dtype):
+    """With the cache donated, a decode tick moves O(rows) bytes of the
+    K/V levels: no copy or transpose of any level-sized array, and every
+    cache leaf aliased input -> output.  (The int8 pool's (NP, nr) scale
+    arrays, 1/32 of its bytes at head_dim 128, are not held to this: the
+    TPU keeps them lane-transposed, and the attend's row gather
+    relayouts them -- PERF.md, open questions.)"""
+    args = _decode_args(one_chip, cache_kind, 128, dtype)
+    text = jax.jit(_decode_step(cache_kind), donate_argnums=0).lower(
+        *args).compile().as_text()
+    assert "tpu_custom_call" in text
+    _assert_cache_in_place(text, jax.tree.leaves(args[0]), ndim=3)
+
+
+def test_page_copy_program_writes_in_place(one_chip):
+    """A flush's page copies for ``yi6b-decode-8k``'s pool (8 stacked
+    layers, 10 levels, bf16, head_dim 128, 8 slots, 4 kv heads) compile
+    to one program that moves O(pages) bytes: no copy or transpose of
+    any level-sized array, and every cache leaf aliased input -> output."""
+    from repro.serve import paged_cache as pc
+    Hkv, layers = 4, 8
+    pool = pc.PagePool(slots=8, max_len=16384, nr=NR, pool_pages=8 * 1024)
+    assert pool.M == 10
+    one = jax.eval_shape(lambda: hd.init_paged_pool(
+        [n * Hkv for n in pool.num_pages], NR, 128, 128, jnp.bfloat16))
+    caches = jax.tree.map(
+        lambda a: _sds(one_chip, (layers,) + a.shape, a.dtype), one)
+    table = _sds(one_chip, pc._copy_table_shape(caches, Hkv, True) + (2,),
+                 jnp.int32)
+    text = pc._copy_program.lower(caches, table, Hkv,
+                                  True).compile().as_text()
+    _assert_cache_in_place(text, jax.tree.leaves(caches), ndim=4)
