@@ -36,34 +36,9 @@ def reference_logits(cfg: dict, seed: int, seqs, prec="f32", pad_to=None):
     import jax
     import jax.numpy as jnp
     from bench import harness, weights
-    ref = harness.reference(cfg)
     w = jax.jit(lambda k: weights.canonical(k, cfg, jnp.dtype(cfg["dtype"])))(
         weights.key_from_seed(seed, 1))
-    layer_keys = [k for k, (shape, _) in weights.shapes(cfg).items()
-                  if len(shape) >= 2 and shape[0] == cfg["num_layers"]
-                  and k not in ("embed", "lm_head")]
-    blk = jax.jit(lambda lw, h: ref.block(
-        jax.tree.map(lambda x: x.astype(jnp.float32), lw), 0, cfg, h, prec,
-        head_groups=cfg["num_kv_heads"], mlp_rows=8192))
-    embed = jax.jit(lambda e, t: e[t].astype(jnp.float32))
-    top = jax.jit(lambda w, h: ref.head(
-        jax.tree.map(lambda x: x.astype(jnp.float32), w), cfg,
-        ref.rmsnorm(h, w["final_norm"].astype(jnp.float32),
-                    cfg["rms_norm_eps"]), prec))
-    head_w = {k: w[k] for k in ("embed", "lm_head", "final_norm") if k in w}
-    out = []
-    with jax.default_matmul_precision("highest"):
-        for prompt, served in seqs:
-            toks = np.concatenate([prompt, served[:-1]]).astype(np.int32)
-            n, P = len(served), len(prompt)
-            L = pad_to or len(toks)
-            toks = np.pad(toks, (0, L - len(toks)))
-            h = embed(w["embed"], jnp.asarray(toks)[None])
-            for i in range(cfg["num_layers"]):
-                h = blk({k: w[k][i:i + 1] for k in layer_keys}, h)
-            out.append(np.asarray(top(head_w, h[0, P - 1:P - 1 + n])))
-            del h
-    return out
+    return harness.reference(cfg).served_logits(w, cfg, seqs, prec, pad_to)
 
 
 def served_gap(ref_logits, seqs) -> float:
@@ -78,9 +53,10 @@ class Engine:
     def __init__(self, cfg: dict, mix: dict, seed: int):
         import jax
         import jax.numpy as jnp
-        from bench import weights
+        from bench import harness, weights
         from repro.serve import ServeEngine
-        params = jax.jit(lambda k: weights.to_program(
+        lay = harness.layout(cfg)
+        params = jax.jit(lambda k: lay.to_program(
             weights.canonical(k, cfg, jnp.dtype(cfg["dtype"])), cfg))(
             weights.key_from_seed(seed, 1))
         self.eng = ServeEngine(model_config(cfg), params,
@@ -88,20 +64,17 @@ class Engine:
                                paged=True, greedy=True)
 
     def warm_copies(self):
-        """Compile (or load) the page copies that ticks make as sessions
-        cross into new pages: for every level, a copy of 1 to ``slots``
-        pages.  The engine runs them as separate eager operations, one
-        program per level and count, so without this they would compile
-        inside the window.  The copies write the zero page into free
-        pages, which allocation zeroes anyway."""
+        """Compile (or load) the program that makes the page copies of
+        ticks whose sessions cross into new pages, so that it does not
+        compile inside the window.  Its table has one shape for every
+        flush, so one copy of the zero page into a free page, which
+        allocation zeroes anyway, is enough."""
         from repro.serve import paged_cache as pc
         eng = self.eng
-        for level in range(eng.pool.M):
-            free = eng.pool.free[level]
-            for c in range(1, eng.slots + 1):
-                eng.caches = pc.apply_copies(
-                    eng.caches, {level: [(pc.ZERO, p) for p in free[:c]]},
-                    eng.cfg.num_kv_heads, eng._stacked)
+        level = next(l for l, f in enumerate(eng.pool.free) if f)
+        eng.caches = pc.apply_copies(
+            eng.caches, {level: [(pc.ZERO, eng.pool.free[level][0])]},
+            eng.cfg.num_kv_heads, eng._stacked)
 
     def admit_all(self, reqs):
         """Submit and admit each request in turn (closed batch)."""

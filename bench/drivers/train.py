@@ -39,8 +39,8 @@ MAX_AHEAD = 64        # at most this many: short steps would queue thousands
 
 # Keys of a configuration file that describe it to the reader and the
 # harness; every other key is the model's and has to reach the program.
-DESCRIPTIVE = {"source", "deployment", "reference", "reduced", "published",
-               "assumed", "precision", "train"}
+DESCRIPTIVE = {"source", "deployment", "reference", "layout", "reduced",
+               "published", "assumed", "precision", "train"}
 
 
 def model_config(cfg: dict):
@@ -120,7 +120,7 @@ def reference_readings(cfg: dict, seed: int, batches, prec="f32") -> dict:
         n = blocks.shape[0]
         loss, g = loss / n, jax.tree.map(lambda x: x / n, g)
         w, m, v, g = ref.adamw_step(w, m, v, g, step, tc)
-        return store(w), m, v, loss, weights.leaf_norms(g)
+        return store(w), m, v, loss, weights.leaf_norms(g, cfg)
 
     step = jax.jit(one)
     w0 = store(w0)
@@ -136,7 +136,7 @@ def reference_readings(cfg: dict, seed: int, batches, prec="f32") -> dict:
             if i == 0:
                 grad = {k: float(x) for k, x in gn.items()}
         change = jax.jit(lambda a, b: weights.leaf_norms(
-            jax.tree.map(jnp.subtract, a, b)))(w, w0)
+            jax.tree.map(jnp.subtract, a, b), cfg))(w, w0)
     return {"loss": losses, "grad": grad,
             "change": {k: float(x) for k, x in change.items()}}
 
@@ -159,18 +159,19 @@ class Program:
     def __init__(self, cfg: dict, seed: int, batch_shape):
         import jax
         import jax.numpy as jnp
-        from bench import weights
+        from bench import harness, weights
         from repro.models import set_mesh_axes
         from repro.train import TrainState
         from repro.train.loop import make_optimizer, make_train_step
         self.cfg = cfg
+        lay = harness.layout(cfg)
         mc, tc = model_config(cfg), train_config(cfg)
         set_mesh_axes(1)
         opt = make_optimizer(tc)
 
         def init(key):
             w = weights.canonical(key, cfg, jnp.dtype(cfg["dtype"]))
-            params = weights.to_program(w, cfg)
+            params = lay.to_program(w, cfg)
             return TrainState(jnp.zeros((), jnp.int32), params,
                               opt.init(params), None)
 
@@ -180,9 +181,9 @@ class Program:
         self.step = jax.jit(step, donate_argnums=(0,)).lower(
             self.state, {"tokens": tokens}).compile()
         self._norms = jax.jit(lambda t: weights.leaf_norms(
-            weights.from_program(t, cfg)))
+            lay.from_program(t, cfg), cfg))
         self._diff = jax.jit(lambda a, b: weights.leaf_norms(
-            weights.from_program(jax.tree.map(jnp.subtract, a, b), cfg)))
+            lay.from_program(jax.tree.map(jnp.subtract, a, b), cfg), cfg))
 
     def __call__(self, batch):
         self.state, metrics = self.step(self.state, {"tokens": batch})

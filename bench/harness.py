@@ -5,7 +5,8 @@ A cell (``workloads`` entry of ``BENCHMARK.json``) names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<mix>.json``).  The mix's ``kind`` picks the driver
 (``bench/drivers/<kind>.py``), the configuration its plain reference
-(``bench/refs/<reference>.py``), the cell's name its limits
+(``bench/refs/<reference>.py``) and its weight layout
+(``bench/layouts/<layout>.py``), the cell's name its limits
 (``bench/limits/<cell>.json``), and each per-layer metric its reader
 (``bench/metrics/<metric>.py``).  Adding any of them is adding files and
 entries.
@@ -83,6 +84,12 @@ def driver(kind: str):
 def reference(cfg: dict):
     """The plain reference a configuration names (``bench/refs/<name>.py``)."""
     return importlib.import_module(f"bench.refs.{cfg['reference']}")
+
+
+def layout(cfg: dict):
+    """The weight layout a configuration names (``bench/layouts/<name>.py``);
+    there is no default."""
+    return importlib.import_module(f"bench.layouts.{cfg['layout']}")
 
 
 def metric_reader(name: str):

@@ -5,7 +5,8 @@ Written from the published descriptions alone, in plain ``jax.numpy``:
 Llama-style pre-norm blocks (RMSNorm, rotate-half RoPE, grouped-query
 attention, SwiGLU MLP) and the H-Transformer-1D attention of
 arXiv:2107.11906 in its causal "fine-q" form.  It imports nothing of the
-system under test and takes only canonical weights (``bench.weights``).
+system under test and takes only canonical weights in the dense layout
+(``bench/layouts/dense.py``, made by ``bench.weights``).
 
 Causal fine-q H1D, for query ``i`` and key ``j <= i`` with block size
 ``nr``: the pair is attended at the smallest level ``l`` at which
@@ -30,6 +31,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 F32 = jnp.float32
 
@@ -203,6 +205,34 @@ def loss(w, cfg, tokens, prec="f32"):
     logz = jax.nn.logsumexp(logits, -1)
     gold = jnp.take_along_axis(logits, tgt[..., None], -1)[..., 0]
     return jnp.mean(logz - gold)
+
+
+def served_logits(w, cfg, seqs, prec="f32", pad_to=None):
+    """For each (prompt, served) pair, the logits (n, V) at the positions
+    that produced the served tokens, as numpy; each sequence (padded to
+    ``pad_to``) runs one jitted layer at a time, so that it fits."""
+    head_w = {k: w[k] for k in ("embed", "lm_head", "final_norm") if k in w}
+    layer_w = {k: x for k, x in w.items() if k not in head_w}
+    blk = jax.jit(lambda lw, h: block(
+        jax.tree.map(lambda x: x.astype(F32), lw), 0, cfg, h, prec,
+        head_groups=cfg["num_kv_heads"], mlp_rows=8192))
+    embed = jax.jit(lambda e, t: e[t].astype(F32))
+    top = jax.jit(lambda w, h: head(
+        jax.tree.map(lambda x: x.astype(F32), w), cfg,
+        rmsnorm(h, w["final_norm"].astype(F32), cfg["rms_norm_eps"]), prec))
+    out = []
+    with jax.default_matmul_precision("highest"):
+        for prompt, served in seqs:
+            toks = np.concatenate([prompt, served[:-1]]).astype(np.int32)
+            n, P = len(served), len(prompt)
+            L = pad_to or len(toks)
+            toks = np.pad(toks, (0, L - len(toks)))
+            h = embed(w["embed"], jnp.asarray(toks)[None])
+            for i in range(cfg["num_layers"]):
+                h = blk({k: x[i:i + 1] for k, x in layer_w.items()}, h)
+            out.append(np.asarray(top(head_w, h[0, P - 1:P - 1 + n])))
+            del h
+    return out
 
 
 def adamw_lr(tc, step):

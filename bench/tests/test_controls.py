@@ -23,7 +23,7 @@ def logit_scale(name):
     """``served_gap`` is in logits, whose spread under the benchmark's
     weights (head scale 0.02, unit-RMS final norm) grows as
     sqrt(d_model): the small model's gaps are smaller by that ratio."""
-    return (tiny.WIDTHS[name]["d_model"]
+    return (tiny.widths(name)["d_model"]
             / tiny.full_config(name)["d_model"]) ** 0.5
 
 

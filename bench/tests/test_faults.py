@@ -74,10 +74,34 @@ def test_serve_token_altered(monkeypatch):
     assert not r["correct"], r["checks"]
 
 
+def _donating(fn):
+    """The tick as it runs with its caches donated: the input buffers
+    are gone once it returns."""
+    def tick(params, caches, *rest):
+        out = fn(params, caches, *rest)
+        for x in jax.tree.leaves(caches):
+            x.delete()
+        return out
+    return tick
+
+
+def _withhold(fn, tick, eng, params, caches, *rest):
+    """The tick's logits with its input caches handed back unchanged,
+    from a copy taken before the call, so that a tick that donates its
+    caches still runs on."""
+    keep = jax.tree.map(jnp.copy, caches)
+    logits, _ = fn(params, caches, *rest)
+    return logits, keep
+
+
 def test_serve_state_unchanged(monkeypatch):
-    def fault(fn, tick, eng, params, caches, *rest):
-        logits, _ = fn(params, caches, *rest)
-        return logits, caches
-    _wrap_decode(monkeypatch, fault)
+    _wrap_decode(monkeypatch, _withhold)
+    r = tiny.run_cell("yi6b-decode-8k")
+    assert not r["correct"], r["checks"]
+
+
+def test_serve_state_unchanged_donated(monkeypatch):
+    _wrap_decode(monkeypatch,
+                 lambda fn, *args: _withhold(_donating(fn), *args))
     r = tiny.run_cell("yi6b-decode-8k")
     assert not r["correct"], r["checks"]

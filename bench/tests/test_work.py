@@ -6,6 +6,7 @@ import os
 import numpy as np
 
 from bench import work
+from bench.layouts import dense
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -48,7 +49,7 @@ def test_train_step_by_hand():
     c = cfg("h1d-lm-144m")
     # per layer 1024*1024 + 2*1024*1024 + 1024*1024 + 3*1024*4096, six
     # layers, plus the tied head 1024*32768
-    assert work.matmul_params(c) == 6 * 16_777_216 + 33_554_432
+    assert dense.matmul_params(c) == 6 * 16_777_216 + 33_554_432
     # L=4096, nr=16: level 0 256*136 + 16*4080 = 100096; levels 1..7
     # 12 * sum(4096 - 16*2**l) = 12 * 24608
     pairs = 100_096 + 12 * 24_608
@@ -70,13 +71,13 @@ def test_prefill_by_hand():
     p = work.prefill(c, [16, 48])
     n = 8 * 4 * 128 * 32 * (136 + 1048)
     assert p["h1d_flops"] == n
-    assert p["flops"] == 2 * work.matmul_params(c) * 64 + n
+    assert p["flops"] == 2 * dense.matmul_params(c) * 64 + n
 
 
 def test_decode_tick_by_hand():
     c = cfg("yi-6b")
     # per layer 4096*4096*2 + 2*4096*512 + 3*4096*11008; head 4096*64000
-    assert work.matmul_params(c) == 8 * 173_015_040 + 262_144_000
+    assert dense.matmul_params(c) == 8 * 173_015_040 + 262_144_000
     t = work.decode_tick(c, [30000] * 4)
     attn = 4 * 8 * 4 * 128 * 32 * 145
     assert t["h1d_flops"] == attn

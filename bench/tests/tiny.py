@@ -1,25 +1,18 @@
 """Small shapes of the benchmark's cells, so that a whole run (set-up,
-window, reference, comparison) can be driven on the CPU in a test."""
+window, reference, comparison) can be driven on the CPU in a test.
+
+A configuration's small shapes are ``small/configs/<config>.json`` and a
+traffic mix's ``small/traffic/<mix>.json`` beside this file: each holds
+the keys that replace the full file's.  A mix without one runs as it
+is."""
 import json
 import os
 import types
 
 from bench import gen, harness
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
-    __file__))))
-WIDTHS = {
-    "h1d-lm-144m": dict(num_layers=2, d_model=64, num_heads=4,
-                        num_kv_heads=4, head_dim=16, d_ff=128,
-                        vocab_size=512, nr=8),
-    "yi-6b": dict(num_layers=2, d_model=64, num_heads=8, num_kv_heads=2,
-                  head_dim=8, d_ff=128, vocab_size=512, nr=8),
-}
-MIXES = {
-    "packed-4k": dict(seq_len=128, batch=2, doc_median=32, doc_max=256),
-    "decode-8k": dict(requests=2, slots=2, prompt_len=200, out_min=600,
-                       out_max=600, max_len=1024),
-}
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
 
 
 def full_config(name):
@@ -27,12 +20,19 @@ def full_config(name):
         return json.load(f)
 
 
+def widths(name):
+    """The small shapes of configuration ``name``."""
+    return harness.load_json(HERE, "small", "configs", f"{name}.json")
+
+
 def config(name, root=ROOT):
-    return dict(full_config(name), **WIDTHS[name])
+    return dict(full_config(name), **widths(name))
 
 
 def mix(name, _load=gen.load):
-    return dict(_load(name), **MIXES.get(name, {}))
+    path = os.path.join(HERE, "small", "traffic", f"{name}.json")
+    small = harness.load_json(path) if os.path.exists(path) else {}
+    return dict(_load(name), **small)
 
 
 def patch(monkeypatch):

@@ -5,8 +5,9 @@ Nothing here depends on how the system implements the operation, so a
 change of tiling or kernel leaves these numbers as they are.
 
 * Matrix products: 2 FLOPs per multiply-add.  A model's token costs
-  ``2 * matmul_params`` forward (the embedding gather is no product; a
-  tied output head is).  Training costs three times the forward.
+  ``2 * matmul_params`` forward, where the configuration's layout
+  (``bench/layouts/<layout>.py``) counts the product weights a token
+  computes with.  Training costs three times the forward.
 * The causal fine-q H1D operator: each attended (query, key) pair costs
   ``2 D`` for the score and ``2 D`` for the value product; a coarse key
   counts as one pair.  The backward pass needs twice the forward's
@@ -15,32 +16,19 @@ change of tiling or kernel leaves these numbers as they are.
 * Least bytes: every input read once and every output written once:
   q, k, v and the output, plus one f32 log-sum-exp per query row and
   head, which the backward pass reads back.
-* A decode tick reads every weight once, the embedding rows of its
-  tokens, and per session, layer and KV head the cache rows its query
-  attends (a coarse row is one row), and reads and writes one row per
-  hierarchy level for the cache update.
+* A decode tick reads the weights its layout counts for a tick
+  (``tick_weights``: for a dense model every weight once and the
+  embedding rows of its tokens), and per session, layer and KV head the
+  cache rows its query attends (a coarse row is one row), and reads and
+  writes one row per hierarchy level for the cache update.
 """
 from __future__ import annotations
 
 import numpy as np
 
+from bench import harness
+
 BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1}
-
-
-def matmul_params(cfg: dict) -> int:
-    d, ff = cfg["d_model"], cfg["d_ff"]
-    hq = cfg["num_heads"] * cfg["head_dim"]
-    hkv = cfg["num_kv_heads"] * cfg["head_dim"]
-    per_layer = d * hq + 2 * d * hkv + hq * d + 3 * d * ff
-    return cfg["num_layers"] * per_layer + d * cfg["vocab_size"]
-
-
-def tick_weight_bytes(cfg: dict, tokens: int) -> int:
-    """Weights a decode tick of ``tokens`` tokens reads: every product's
-    weights and norm gains once, and one embedding row per token."""
-    d = cfg["d_model"]
-    n = matmul_params(cfg) + (2 * cfg["num_layers"] + 1 + tokens) * d
-    return n * BYTES[cfg["dtype"]]
 
 
 def h1d_pairs_at(i, nr: int):
@@ -87,7 +75,8 @@ def train_step(cfg: dict, B: int, L: int) -> dict:
     n = cfg["num_layers"]
     ff, fb = h1d_forward(cfg, B, L)
     bf, bb = h1d_backward(cfg, B, L)
-    return {"flops": 6 * matmul_params(cfg) * B * L + n * (ff + bf),
+    params = harness.layout(cfg).matmul_params(cfg)
+    return {"flops": 6 * params * B * L + n * (ff + bf),
             "h1d_flops": n * (ff + bf), "h1d_bytes": n * (fb + bb)}
 
 
@@ -99,8 +88,9 @@ def prefill(cfg: dict, lengths) -> dict:
     for L in lengths:
         f, b = h1d_forward(cfg, 1, int(L))
         fl, by = fl + f, by + b
-    return {"flops": 2 * matmul_params(cfg) * int(np.sum(lengths))
-            + n * fl, "h1d_flops": n * fl, "h1d_bytes": n * by}
+    params = harness.layout(cfg).matmul_params(cfg)
+    return {"flops": 2 * params * int(np.sum(lengths)) + n * fl,
+            "h1d_flops": n * fl, "h1d_bytes": n * by}
 
 
 def decode_tick(cfg: dict, positions) -> dict:
@@ -115,8 +105,9 @@ def decode_tick(cfg: dict, positions) -> dict:
     attn_flops = n * 4 * D * hq * pairs
     attn_bytes = n * hkv * D * 2 * b * (pairs + 2 * levels) \
         + n * len(pos) * 2 * hq * D * b
-    flops = 2 * matmul_params(cfg) * len(pos) + attn_flops
-    bytes_ = tick_weight_bytes(cfg, len(pos)) + attn_bytes
+    lay = harness.layout(cfg)
+    flops = 2 * lay.matmul_params(cfg) * len(pos) + attn_flops
+    bytes_ = lay.tick_weights(cfg, len(pos)) * b + attn_bytes
     return {"flops": flops, "bytes": bytes_, "h1d_flops": attn_flops,
             "h1d_bytes": attn_bytes}
 
